@@ -24,10 +24,11 @@ benchmark asserts the plan never retraced across reps (the jit cache
 holds exactly one entry).
 
 Two more row families measure the SPATIAL pipeline on a multi-device
-``(stage, data)`` host-platform mesh (device counts must be forced before
+``(stage, data)`` mesh. On the CPU the device count must be forced before
 JAX initializes, so both are measured in one subprocess —
 ``python -m benchmarks.e2e_bench --pipelined-json --handoff <npz>`` with
-``--xla_force_host_platform_device_count=8``):
+``--xla_force_host_platform_device_count=8``; on an accelerator they are
+measured in this process, on its real devices:
 
 - ``path: pipeline_sweep`` — the µbatch/batch-grain crossover sweep:
   cifar10 and svhn fp32 across (n_microbatches, grain, overlap) configs,
@@ -40,9 +41,8 @@ JAX initializes, so both are measured in one subprocess —
   verified and ``pipeline_speedup`` recorded vs the single-device plan.
 
 The single-device references (logits + frames/s per group size) are
-measured ONCE in the main process and handed to the subprocess as an
-``.npz`` file — the subprocess never recompiles or re-runs the reference
-plan.
+measured ONCE in the main process and handed over as an ``.npz`` file —
+the subprocess never recompiles or re-runs the reference plan.
 """
 from __future__ import annotations
 
@@ -177,7 +177,7 @@ def _sweep_rows_here(handoff) -> list:
     the sweep topologies, each point verified against the pre-measured
     single-device logits and stamped with its full configuration (these
     rows are the autotuner's measurement source)."""
-    from repro.core.dhm.pipeline import PipelineConfig
+    from repro.core.dhm.pipeline import PipelineConfig, make_pipeline_mesh
 
     rows = []
     for name in SWEEP_TOPOS:
@@ -185,7 +185,7 @@ def _sweep_rows_here(handoff) -> list:
         params = init_cnn(jax.random.PRNGKey(0), topo)
         S = _stages_of(name)
         data = 2
-        mesh = jax.make_mesh((S, data), ("stage", "data"))
+        mesh = make_pipeline_mesh((S, data), ("stage", "data"))
         plan = compile_dhm(topo, params, n_stages=S)
         for M, mb, overlap in SWEEP_GRID:
             group = M * mb
@@ -247,6 +247,12 @@ def _pipelined_rows_here(handoff_path: str) -> list:
 
     handoff = dict(np.load(handoff_path))
     n_dev = len(jax.devices())
+    need = max(_stages_of(n) for n in PIPE_TOPOS) * 2
+    if n_dev < need:
+        raise RuntimeError(
+            f"pipelined bench needs {need} {jax.default_backend()} devices, "
+            f"have {n_dev}"
+        )
     rows = _sweep_rows_here(handoff)
     sweep_rows = list(rows)
 
@@ -279,13 +285,9 @@ def _pipelined_rows_here(handoff_path: str) -> list:
         topo = ALL_TOPOLOGIES[name]
         bits = PAPER_BITS[name]
         S = _stages_of(name)
-        data = max(1, n_dev // S)
-        if S * data > n_dev:
-            raise RuntimeError(
-                f"pipelined bench needs {S * data} devices, have {n_dev}"
-            )
+        data = n_dev // S
         params = init_cnn(jax.random.PRNGKey(0), topo)
-        mesh = jax.make_mesh((S, data), ("stage", "data"))
+        mesh = make_pipeline_mesh((S, data), ("stage", "data"))
         for label, quant in (
             ("fp32", QuantSpec()),
             ("quant", QuantSpec(weight_bits=bits, act_bits=bits)),
@@ -358,12 +360,15 @@ def _pipelined_rows_here(handoff_path: str) -> list:
 
 
 def run_pipelined() -> list:
-    """The ``path: pipeline_sweep`` + ``path: e2e_pipelined`` rows,
-    measured in a subprocess with 8 forced host-platform devices (the
-    flag must be set before JAX initializes, and the main benchmark
-    process may be single-device). The single-device references are
-    measured HERE first and handed off — the subprocess never runs the
-    reference plan."""
+    """The ``path: pipeline_sweep`` + ``path: e2e_pipelined`` rows. The
+    single-device references are measured HERE first and handed off.
+
+    On the CPU backend the rows are measured in a subprocess with 8
+    forced host-platform devices (the flag must be set before JAX
+    initializes, and this process may be single-device); the subprocess
+    never runs the reference plan. On an accelerator this process holds
+    the chips — a child could not reach them — so the rows are measured
+    here, on the real devices, and a host with too few raises."""
     repo_root = pathlib.Path(__file__).resolve().parents[1]
 
     # Reference pass: one plan per (topology, precision), measured at
@@ -392,6 +397,8 @@ def run_pipelined() -> list:
     with tempfile.TemporaryDirectory() as td:
         handoff = os.path.join(td, "single_device_refs.npz")
         _write_handoff(plans, handoff)
+        if jax.default_backend() != "cpu":
+            return _pipelined_rows_here(handoff)
         res = subprocess.run(
             [sys.executable, "-m", "benchmarks.e2e_bench",
              "--pipelined-json", "--handoff", handoff],

@@ -50,6 +50,9 @@ def main() -> None:
                     help="full Fig. 3 sweep (all 3 CNNs)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         e2e_bench,
         fig3_bitwidth,

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.dhm import (
     CYCLONE_V_5CGXFC9E7,
     MultiplierStrategy,
@@ -26,6 +27,7 @@ from repro.core.dhm import (
     dhm_throughput_gops,
     estimate_resources,
 )
+from repro.core.dhm.pipeline import make_pipeline_mesh
 from repro.core.dhm.resources import ParamClassFractions
 from repro.models.cnn import LENET5, cnn_apply_reference
 from repro.paper.analysis import classify_model
@@ -124,7 +126,7 @@ def main():
     # (stage, data) mesh adds data-parallel batch sharding on top.
     for st in plan.stages:
         print(f"  stage {st.index}: {st.io.in_shape} -> {st.io.out_shape}")
-    mesh = jax.make_mesh((2, 2), ("stage", "data"))
+    mesh = make_pipeline_mesh((2, 2), ("stage", "data"))
     mbs = jnp.asarray(
         np.random.default_rng(1).normal(size=(6, 4, 28, 28, 1)), jnp.float32
     )
@@ -161,4 +163,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -9,6 +9,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.data import TokenStreamConfig, synthetic_token_batches
 from repro.models import transformer as T
@@ -79,4 +80,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

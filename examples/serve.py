@@ -12,6 +12,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.models import transformer as T
 from repro.models.layers import pack_params_pow2
@@ -87,4 +88,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -22,8 +22,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.dhm import Engine, QuantSpec, Router, compile_dhm
 from repro.core.dhm.faults import DispatchError, FaultPlan, NaNActivation
+from repro.core.dhm.pipeline import make_pipeline_mesh
 from repro.models.cnn import ALL_TOPOLOGIES, init_cnn
 
 
@@ -222,7 +224,7 @@ def main():
               f"({st.cost_flops / 1e6:.2f} Mflop)")
     mesh_axes = (("stage", "data") if data > 1 else ("stage",))
     mesh_shape = (n_stages, data) if data > 1 else (n_stages,)
-    mesh = jax.make_mesh(mesh_shape, mesh_axes)
+    mesh = make_pipeline_mesh(mesh_shape, mesh_axes)
     engp = Engine(
         plan_s, microbatch=args.microbatch, mesh=mesh, n_microbatches=4,
         data_axis="data" if data > 1 else None,
@@ -241,4 +243,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

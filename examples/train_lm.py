@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ArchConfig
 from repro.data import ShardedLoader, TokenStreamConfig, synthetic_token_batches
 from repro.models import transformer as T
@@ -147,4 +148,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -7,8 +7,9 @@ diagnostics:
   serving code — varying request shapes retrace the op per shape
   (~100 ms/flush); pack with numpy on the host instead.
 - ``DHM002`` param stacking (``jnp.stack``/``jnp.concatenate``) inside a
-  jitted function — on 2D meshes shard_map receives a mis-partitioned
-  operand; box and stack eagerly, pass resident leaves as arguments.
+  jitted function — the params are re-stacked on every dispatch instead
+  of staying resident; box and stack eagerly, pass resident leaves as
+  arguments.
 - ``DHM003`` timing a jax dispatch without ``block_until_ready`` —
   async dispatch returns before the work runs, so the window measures
   nothing.
@@ -187,9 +188,9 @@ def _eager_concat(tree, src, relpath):
     ),
 )
 def _stack_inside_jit(tree, src, relpath):
-    """jnp.stack/jnp.concatenate inside a jitted function: on 2D meshes
-    the stacked operand reaches shard_map mis-partitioned (the PR-5/7
-    sharp edge). Box + stack eagerly; pass resident leaves as args."""
+    """jnp.stack/jnp.concatenate inside a jitted function: the params are
+    re-stacked on every dispatch instead of staying resident. Box + stack
+    eagerly; pass resident leaves as args."""
     owner = _parent_functions(tree)
     jitted = _jitted_functions(tree)
     out = []

@@ -602,9 +602,9 @@ class Engine:
             edge_mode=self.edge_mode,
         )
         # Box + stack + make the per-stage params resident ONCE, here
-        # (eagerly — stacking inside the jit trace would hand shard_map a
-        # mis-partitioned operand on 2D meshes); the jitted closure then
-        # takes the resident leaves as arguments.
+        # (eagerly — stacked inside the jit trace they would be re-stacked
+        # on every dispatch); the jitted closure then takes the resident
+        # leaves as arguments.
         runner = build_plan_pipeline(
             plan, mesh=mesh, cfg=cfg, microbatch=microbatch
         )

@@ -61,7 +61,7 @@ from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 
 class CollectiveTimeout(RuntimeError):
@@ -397,30 +397,27 @@ def _validate_io_chain(io_specs: Sequence[StageIOSpec]):
 # The executor.
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    # jax.shard_map only exists on newer jax; fall back to the experimental
-    # home (same API modulo the check_rep/check_vma rename).
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+def make_pipeline_mesh(shape, axes, *, devices=None) -> jax.sharding.Mesh:
+    """The mesh the spatial pipeline runs on: ``jax.make_mesh`` with every
+    axis ``Auto``. JAX makes new mesh axes ``Explicit`` by default, and
+    the executor's stage selection and param placement rely on the
+    compiler choosing shardings (``Auto``); every caller builds its stage
+    and ``(stage, data)`` meshes here. ``devices`` defaults to
+    ``jax.devices()``."""
+    axes = tuple(axes)
+    return jax.make_mesh(
+        tuple(shape), axes, axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
     )
 
 
 @dataclasses.dataclass(frozen=True)
 class PipelinedRunner:
     """A built spatial pipeline: the shard_map'd GPipe executor plus the
-    boxed per-stage parameter leaves, stacked ONCE at build time (eagerly
-    — never inside an enclosing ``jit`` trace, where a 2D-mesh shard_map
-    operand produced by a traced ``stack`` is mis-partitioned on
-    jax 0.4.37) and laid out so each stage's device group holds exactly
-    its own weights (DHM's private resources).
+    boxed per-stage parameter leaves, stacked ONCE at build time (eagerly,
+    outside any enclosing ``jit`` trace) and laid out so each stage's
+    device group holds exactly its own weights (DHM's private
+    resources).
 
     ``runner(microbatches)`` runs the resident leaves; ``runner.apply``
     is the pure ``(leaves, microbatches) -> outputs`` function for
@@ -488,6 +485,15 @@ def build_pipeline(
     """
     S, M = cfg.n_stages, cfg.n_microbatches
     ax = cfg.stage_axis
+    explicit = [
+        a for a, t in zip(mesh.axis_names, mesh.axis_types)
+        if t != AxisType.Auto
+    ]
+    if explicit:
+        raise ValueError(
+            f"mesh axes {explicit} are not Auto; build pipeline meshes "
+            "with make_pipeline_mesh"
+        )
     if mesh.shape[ax] != S:
         raise ValueError(
             f"mesh axis {ax!r} has {mesh.shape[ax]} devices, need {S}"
@@ -668,7 +674,10 @@ def build_pipeline(
         P(None, dax) if dax else P(),  # µbatch stream (only stage 0 reads it)
     )
     out_specs = P(ax, None, dax) if dax else P(ax)
-    shmap = _shard_map(_per_stage, mesh, in_specs, out_specs)
+    shmap = jax.shard_map(
+        _per_stage, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
     def _apply(leaves, microbatches):
         if microbatches.shape[0] != M:

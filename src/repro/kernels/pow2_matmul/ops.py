@@ -56,7 +56,7 @@ def pow2_matmul(
     scale: jax.Array,
     *,
     block_m: int = 128,
-    block_n: int = 128,
+    block_n: int = 256,
     block_k: int = 128,
     out_dtype=jnp.float32,
     backend: str = DEFAULT_BACKEND,  # pallas | pallas_interpret | ref

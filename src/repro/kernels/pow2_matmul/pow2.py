@@ -15,8 +15,10 @@ The TPU-native rendering of the paper's constant-specialized multipliers:
   into the activation's fixed-point alignment).
 
 Grid: (M/bm, N/bn, K/bk), K innermost; accumulation in an f32 VMEM scratch,
-written out (scaled) on the last K step. Block shapes default to MXU-aligned
-128x128x128; the packed weight block is (bk, bn//2) uint8.
+written out (scaled) on the last K step. Block shapes default to
+128x256x128, so the packed weight block (bk, bn//2) uint8 spans a whole
+128-lane tile. Inside a block the kernel holds the even columns first and
+the odd ones second (see ``_unpack_u4``); the wrapper undoes that order.
 """
 from __future__ import annotations
 
@@ -43,10 +45,30 @@ def _decode_codes_f32(codes: jax.Array) -> jax.Array:
 
 
 def _unpack_u4(packed: jax.Array) -> jax.Array:
-    """(bk, bn//2) uint8 -> (bk, bn) uint8, even codes in low nibbles."""
-    lo = jnp.bitwise_and(packed, 0x0F)
-    hi = jnp.right_shift(packed, 4)
-    return jnp.stack([lo, hi], axis=-1).reshape(packed.shape[0], -1)
+    """(bk, bn//2) uint8 -> (bk, bn) int32 codes, block-permuted: the low
+    nibbles (even columns) fill the first half and the high nibbles (odd
+    columns) the second. Side by side is a lane concat, which Mosaic
+    lowers; interleaving them back would take a lane shuffle, so the
+    wrapper permutes the scale and the output columns instead."""
+    p = packed.astype(jnp.int32)
+    return jnp.concatenate(
+        [jnp.bitwise_and(p, 0x0F), jnp.right_shift(p, 4)], axis=-1
+    )
+
+
+def _halves_first(a: jax.Array, bn: int) -> jax.Array:
+    """Reorder the last axis within each bn block: even columns, then odd
+    (the kernel's unpacked column order)."""
+    lead = a.shape[:-1]
+    a = a.reshape(lead + (-1, bn // 2, 2))
+    return jnp.swapaxes(a, -1, -2).reshape(lead + (-1,))
+
+
+def _interleave_halves(a: jax.Array, bn: int) -> jax.Array:
+    """Inverse of :func:`_halves_first`."""
+    lead = a.shape[:-1]
+    a = a.reshape(lead + (-1, 2, bn // 2))
+    return jnp.swapaxes(a, -1, -2).reshape(lead + (-1,))
 
 
 def _pow2_matmul_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, out_dtype):
@@ -80,7 +102,7 @@ def pow2_matmul_pallas(
     scale: jax.Array,  # (N,) f32
     *,
     block_m: int = 128,
-    block_n: int = 128,
+    block_n: int = 256,
     block_k: int = 128,
     out_dtype=jnp.float32,
     interpret: bool = False,
@@ -103,16 +125,17 @@ def pow2_matmul_pallas(
 
     grid = (m // bm, n // bn, k // bk)
     kernel = functools.partial(_pow2_matmul_kernel, out_dtype=out_dtype)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, s: (i, s)),
             pl.BlockSpec((bk, bn // 2), lambda i, j, s: (s, j)),
-            pl.BlockSpec((bn,), lambda i, j, s: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, s: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x, packed, scale)
+    )(x, packed, _halves_first(scale, bn).reshape(1, n))
+    return _interleave_halves(out, bn)

@@ -13,8 +13,10 @@ back:
 
     1. receives its input tile through the BlockSpec pipeline: an
        (R*s x WC*s) body block plus a halo strip on the bottom/right edge
-       (and the corner) — the halo is the line buffer: the only pixels
-       ever fetched twice. The halo width ``hb = max(0, (P_w - P_s)*s +
+       (and the corner); with a single column block the body spans the
+       whole padded width and only the bottom strip remains — the halo
+       is the line buffer: the only pixels ever fetched twice. The halo
+       width ``hb = max(0, (P_w - P_s)*s +
        K - s)`` covers both the conv window overlap (K - s) and the pool
        window overlap ((P_w - P_s) conv rows re-computed so overlapping
        pool windows never straddle a block boundary),
@@ -60,6 +62,7 @@ from repro.kernels.stream_conv.epilogue import (
     apply_epilogue,
     normalize_pool,
     pool_out_dim,
+    strided_view,
     validate_epilogue,
 )
 from repro.kernels.stream_conv.halo import group_geometry
@@ -87,29 +90,15 @@ def _kernel_body(
     bc = x_blk.shape[-1]
     # K*K stride-s shifted views of the tile -> one tall operand. Pure data
     # movement (VPU); the contraction below is the only matmul.
-    taps = []
-    for ki in range(k):
-        band = jax.lax.slice_in_dim(
-            x_blk, ki, ki + (r_conv - 1) * s + 1, stride=s, axis=0
-        )  # (r_conv, ·, bc)
-        for kj in range(k):
-            taps.append(
-                jax.lax.slice_in_dim(
-                    band, kj, kj + (w_conv - 1) * s + 1, stride=s, axis=1
-                )
-            )
-    patches = jnp.stack(taps, axis=2)  # (r_conv, w_conv, k*k, bc)
+    operand = _assemble_taps(x_blk, k, s, r_conv, w_conv)
     if int8_scales is not None:
-        operand = patches.reshape(r_conv * w_conv, k * k * bc)
-        w_flat = w_ref[...].reshape(k * k * bc, -1)
+        w_flat = _merge_rows(w_ref[...])
         # ONE integer MXU matmul per tile, int32 accumulation.
         acc_ref[...] += jnp.dot(
             operand, w_flat, preferred_element_type=jnp.int32
         ).reshape(r_conv, w_conv, -1)
     else:
-        operand = patches.reshape(
-            r_conv * w_conv, k * k * bc
-        ).astype(jnp.float32)
+        operand = operand.astype(jnp.float32)
         w_flat = w_ref[...].reshape(k * k * bc, -1).astype(jnp.float32)
         # ONE MXU matmul per tile (per channel-block accumulation step).
         acc_ref[...] += jnp.dot(
@@ -128,18 +117,18 @@ def _kernel_body(
         o_ref[0] = y.astype(out_dtype)
 
 
-def _fused_kernel_halo(
-    x_cur_ref, x_rh_ref, x_ch_ref, x_corner_ref, w_ref, b_ref, o_ref,
-    acc_ref, **kw,
-):
-    top = jnp.concatenate([x_cur_ref[0], x_ch_ref[0]], axis=1)
-    bot = jnp.concatenate([x_rh_ref[0], x_corner_ref[0]], axis=1)
-    x_blk = jnp.concatenate([top, bot], axis=0)
-    _kernel_body(x_blk, w_ref, b_ref, o_ref, acc_ref, **kw)
-
-
-def _fused_kernel_nohalo(x_cur_ref, w_ref, b_ref, o_ref, acc_ref, **kw):
-    _kernel_body(x_cur_ref[0], w_ref, b_ref, o_ref, acc_ref, **kw)
+def _fused_kernel(*refs, n_tiles, **kw):
+    """refs = the input tiles (body; + bottom halo; + right halo and
+    corner), then w_ref, b_ref, o_ref, acc_ref. Assembles the tiles into
+    one block and runs the shared body."""
+    t = [ref[0] for ref in refs[:n_tiles]]
+    if n_tiles == 4:
+        t = [
+            jnp.concatenate([t[0], t[2]], axis=1),
+            jnp.concatenate([t[1], t[3]], axis=1),
+        ]
+    x_blk = t[0] if len(t) == 1 else jnp.concatenate(t, axis=0)
+    _kernel_body(x_blk, *refs[n_tiles:], **kw)
 
 
 def _block_multiple(k: int, s: int, pw: int, ps: int) -> tuple:
@@ -262,39 +251,45 @@ def stream_conv_fused_pallas(
         int8_scales=int8_scales, out_dtype=out_dtype,
     )
 
+    # With one column block the body tile spans the whole padded width, so
+    # only a bottom halo strip remains and every tile's trailing dims equal
+    # the array's (Mosaic's block-shape rule); column blocking adds the
+    # right strip and the corner.
+    col_w = wc * s + hb if n_wb == 1 else wc * s
     in_specs = [
         pl.BlockSpec(
-            (1, r * s, wc * s, bc),
+            (1, r * s, col_w, bc),
             lambda bb, rb, wb, nb, cb: (bb, rb, wb, cb),
         ),
     ]
-    inputs = [xp]
     if hb:
-        # Halo strips: bottom rows, right cols, and the corner. Element
-        # offset (idx+1)*R*s expressed in hb-sized block units (legal by
-        # the block-multiple rule above).
+        # Halo strips: bottom rows (and, with column blocks, right cols and
+        # the corner). Element offset (idx+1)*R*s expressed in hb-sized
+        # block units (legal by the block-multiple rule above).
         rs_hb = r * s // hb
         ws_hb = wc * s // hb
-        in_specs += [
+        in_specs.append(
             pl.BlockSpec(
-                (1, hb, wc * s, bc),
+                (1, hb, col_w, bc),
                 lambda bb, rb, wb, nb, cb: (bb, (rb + 1) * rs_hb, wb, cb),
-            ),
-            pl.BlockSpec(
-                (1, r * s, hb, bc),
-                lambda bb, rb, wb, nb, cb: (bb, rb, (wb + 1) * ws_hb, cb),
-            ),
-            pl.BlockSpec(
-                (1, hb, hb, bc),
-                lambda bb, rb, wb, nb, cb: (
-                    bb, (rb + 1) * rs_hb, (wb + 1) * ws_hb, cb
+            )
+        )
+        if n_wb > 1:
+            in_specs += [
+                pl.BlockSpec(
+                    (1, r * s, hb, bc),
+                    lambda bb, rb, wb, nb, cb: (bb, rb, (wb + 1) * ws_hb, cb),
                 ),
-            ),
-        ]
-        inputs += [xp, xp, xp]
-        kernel = functools.partial(_fused_kernel_halo, **kw)
-    else:
-        kernel = functools.partial(_fused_kernel_nohalo, **kw)
+                pl.BlockSpec(
+                    (1, hb, hb, bc),
+                    lambda bb, rb, wb, nb, cb: (
+                        bb, (rb + 1) * rs_hb, (wb + 1) * ws_hb, cb
+                    ),
+                ),
+            ]
+    n_tiles = len(in_specs)
+    inputs = [xp] * n_tiles
+    kernel = functools.partial(_fused_kernel, n_tiles=n_tiles, **kw)
     in_specs += [
         pl.BlockSpec((k * k, bc, bn), lambda bb, rb, wb, nb, cb: (0, cb, nb)),
         pl.BlockSpec((bn,), lambda bb, rb, wb, nb, cb: (nb,)),
@@ -328,30 +323,25 @@ def stream_conv_fused_pallas(
 
 
 def _assemble_taps(slab, k: int, s: int, conv_rows: int, conv_cols: int):
-    """Two-step tap assembly: column shifts first (k slices of the slab),
-    then row shifts of the column-assembled operand — 2k strided views
-    instead of k*k, same (rows*cols, k*k*C) matmul operand. Pure VPU data
-    movement; the contraction stays ONE matmul per layer per block."""
-    z = jnp.stack(
-        [
-            jax.lax.slice_in_dim(
-                slab, kj, kj + (conv_cols - 1) * s + 1, stride=s, axis=1
-            )
-            for kj in range(k)
-        ],
-        axis=2,
-    )  # (rows, conv_cols, k, C)
-    patches = jnp.stack(
-        [
-            jax.lax.slice_in_dim(
-                z, ki, ki + (conv_rows - 1) * s + 1, stride=s, axis=0
-            )
-            for ki in range(k)
-        ],
-        axis=2,
-    )  # (conv_rows, conv_cols, ki, kj, C)
-    c = slab.shape[-1]
-    return patches.reshape(conv_rows * conv_cols, k * k * c)
+    """Two-step tap assembly: column shifts first (k strided views of the
+    slab along its sublane dim), then row shifts of each (cheap views
+    along the untiled leading dim), laid side by side on the lane dim in
+    (ki, kj, C) order — the (rows*cols, k*k*C) matmul operand. Pure VPU
+    data movement; the contraction stays ONE matmul per layer per block."""
+    cols = [strided_view(slab, 1, kj, conv_cols, s) for kj in range(k)]
+    taps = [
+        strided_view(z, 0, ki, conv_rows, s) for ki in range(k) for z in cols
+    ]
+    return _merge_rows(jnp.concatenate(taps, axis=-1))
+
+
+def _merge_rows(x):
+    """(R, W, L) -> (R*W, L). Mosaic merges packed int8 rows only when W
+    keeps whole 4-row groups; otherwise the merge runs on int32 (exact)."""
+    r, w, lanes = x.shape
+    if x.dtype == jnp.int8 and w % 4:
+        return x.astype(jnp.int32).reshape(r * w, lanes).astype(jnp.int8)
+    return x.reshape(r * w, lanes)
 
 
 def _pyramid_kernel(*refs, geom, act_bits, int8_scales, out_dtype):
@@ -373,15 +363,7 @@ def _pyramid_kernel(*refs, geom, act_bits, int8_scales, out_dtype):
 
     g0 = geom.layers[0]
     start0 = g0.in_mult * rb + g0.in_off + geom.input_row_shift
-    slab = pl.load(
-        x_ref,
-        (
-            pl.dslice(0, 1),
-            pl.dslice(start0, g0.in_slab_rows),
-            slice(None),
-            slice(None),
-        ),
-    )[0]
+    slab = x_ref[0, pl.ds(start0, g0.in_slab_rows), :, :]
     if int8_scales is None:
         slab = slab.astype(jnp.float32)
 
@@ -407,7 +389,7 @@ def _pyramid_kernel(*refs, geom, act_bits, int8_scales, out_dtype):
         operand = _assemble_taps(
             slab, g.k, g.stride, g.conv_slab_rows, g.conv_cols
         )
-        w_flat = wb[2 * i][...].reshape(g.k * g.k * g.in_ch, g.n_out)
+        w_flat = wb[2 * i][...]  # (k*k*C, N), flattened by the host
         if sc is not None:
             # ONE integer MXU matmul per layer per block -> int32 acc ->
             # exact pow2 dequant.
@@ -494,13 +476,12 @@ def stream_conv_pyramid_pallas(
     ]
     inputs = [xp]
     for g, wt, bs in zip(geom.layers, weights, biases):
-        in_specs.append(
-            pl.BlockSpec(
-                (g.k, g.k, g.in_ch, g.n_out), lambda bb, rb: (0, 0, 0, 0)
-            )
-        )
+        # Taps flattened on the host: the (k*k*C, N) matmul operand is
+        # what the kernel reads, with no in-kernel reshape of packed int8.
+        kkc = g.k * g.k * g.in_ch
+        in_specs.append(pl.BlockSpec((kkc, g.n_out), lambda bb, rb: (0, 0)))
         in_specs.append(pl.BlockSpec((g.n_out,), lambda bb, rb: (0,)))
-        inputs += [wt, bs]
+        inputs += [wt.reshape(kkc, g.n_out), bs]
 
     n_last = n_outs[-1]
     out = pl.pallas_call(

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.quant.fixed_point import FixedPointSpec
@@ -125,23 +126,45 @@ def validate_epilogue(
         raise ValueError(f"act_bits must be >= 2 (or None), got {act_bits}")
 
 
+def strided_view(x, axis: int, start: int, size: int, stride: int):
+    """``size`` elements of ``x`` along ``axis`` from ``start``, every
+    ``stride``-th — ``x[start : start + (size - 1) * stride + 1 : stride]``.
+
+    Written as a unit-stride slice, a split reshape ``(size, stride)`` and
+    an index, because Mosaic lowers neither strided value slices nor
+    gathers but does lower these three. Rows past the end are zero padding
+    that the index never selects, so the values are exactly the strided
+    slice's. Mosaic cannot split a packed int8 dim, so int8 moves as int32
+    (an exact round trip)."""
+    axis %= x.ndim
+    if stride == 1:
+        return jax.lax.slice_in_dim(x, start, start + size, axis=axis)
+    end = start + size * stride
+    if end > x.shape[axis]:
+        pad = [(0, 0)] * x.ndim
+        pad[axis] = (0, end - x.shape[axis])
+        x = jnp.pad(x, pad)
+    dtype = x.dtype
+    if dtype == jnp.int8:
+        x = x.astype(jnp.int32)
+    x = jax.lax.slice_in_dim(x, start, end, axis=axis)
+    x = x.reshape(x.shape[:axis] + (size, stride) + x.shape[axis + 1 :])
+    return jax.lax.index_in_dim(x, 0, axis + 1, keepdims=False).astype(dtype)
+
+
 def _maxpool_window(y, window: int, stride: int):
     """Square max-pool over the trailing (H, W, N) dims of ``y`` via
     window*window shifted strided views — plain jnp ops (elementwise max +
-    static strided slices), so it runs unchanged inside a Pallas kernel
+    :func:`strided_view`), so it runs unchanged inside a Pallas kernel
     body on a VMEM-resident block."""
     *_, h, w, _ = y.shape
     hp = pool_out_dim(h, window, stride)
     wp = pool_out_dim(w, window, stride)
     out = None
     for di in range(window):
+        rows = strided_view(y, -3, di, hp, stride)
         for dj in range(window):
-            v = y[
-                ...,
-                di : di + (hp - 1) * stride + 1 : stride,
-                dj : dj + (wp - 1) * stride + 1 : stride,
-                :,
-            ]
+            v = strided_view(rows, -2, dj, wp, stride)
             out = v if out is None else jnp.maximum(out, v)
     return out
 

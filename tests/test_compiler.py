@@ -465,6 +465,7 @@ class TestStructureCompilerPath:
 PIPELINE_PLAN_SUBPROCESS = r"""
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.dhm.compiler import compile_dhm
+from repro.core.dhm.pipeline import make_pipeline_mesh
 from repro.models.cnn import LENET5, init_cnn
 
 # LeNet5 with 2 stages is genuinely heterogeneous (28x28x1 -> 12x12x20 ->
@@ -473,10 +474,10 @@ from repro.models.cnn import LENET5, init_cnn
 plan = compile_dhm(LENET5, init_cnn(jax.random.PRNGKey(0), LENET5), n_stages=2)
 mbs = jax.random.normal(jax.random.PRNGKey(1), (4, 2, 28, 28, 1))
 seq = jnp.stack([plan.features(mbs[i]) for i in range(4)])
-out = plan.run_pipelined(mbs, mesh=jax.make_mesh((2,), ('stage',)))
+out = plan.run_pipelined(mbs, mesh=make_pipeline_mesh((2,), ('stage',)))
 assert (np.asarray(out) == np.asarray(seq)).all(), 'stage-mesh plan mismatch'
 # 2D (stage, data) mesh: batch sharding composes with the stage pipeline.
-mesh2 = jax.make_mesh((2, 2), ('stage', 'data'))
+mesh2 = make_pipeline_mesh((2, 2), ('stage', 'data'))
 out2 = plan.run_pipelined(mbs, mesh=mesh2, data_axis='data')
 assert np.allclose(np.asarray(out2), np.asarray(seq), atol=1e-5), '2D mismatch'
 print('OK')

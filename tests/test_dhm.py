@@ -296,8 +296,10 @@ class TestConvStage:
 
 PIPELINE_SUBPROCESS = r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.core.dhm.pipeline import PipelineConfig, pipeline_forward, stack_stage_params
-mesh = jax.make_mesh((4,), ('stage',))
+from repro.core.dhm.pipeline import (
+    PipelineConfig, make_pipeline_mesh, pipeline_forward, stack_stage_params,
+)
+mesh = make_pipeline_mesh((4,), ('stage',))
 Ws = [jax.random.normal(jax.random.PRNGKey(i), (8, 8)) * 0.3 for i in range(4)]
 params = stack_stage_params([{'w': w} for w in Ws])
 mbs = jax.random.normal(jax.random.PRNGKey(9), (6, 2, 8))

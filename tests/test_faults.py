@@ -35,7 +35,11 @@ from repro.core.dhm.faults import (
     NaNActivation,
     StalledDispatch,
 )
-from repro.core.dhm.pipeline import CollectiveTimeout, call_with_timeout
+from repro.core.dhm.pipeline import (
+    CollectiveTimeout,
+    call_with_timeout,
+    make_pipeline_mesh,
+)
 from repro.models.cnn import ALL_TOPOLOGIES, init_cnn
 
 TOPO = ALL_TOPOLOGIES["lenet5"]
@@ -429,7 +433,7 @@ class TestAdmissionUnderChaos:
 def _mesh_engine(n_stages=2, **kw):
     params = init_cnn(jax.random.PRNGKey(0), TOPO)
     plan = compile_dhm(TOPO, params, n_stages=n_stages)
-    mesh = jax.make_mesh((n_stages,), ("stage",))
+    mesh = make_pipeline_mesh((n_stages,), ("stage",))
     eng = Engine(
         plan, microbatch=2, mesh=mesh, n_microbatches=2,
         retry_backoff_s=1e-4, **kw,

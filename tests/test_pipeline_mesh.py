@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.dhm.pipeline import make_pipeline_mesh
 from repro.models.cnn import (
     ALL_TOPOLOGIES,
     CNNTopology,
@@ -120,7 +121,7 @@ class TestHeterogeneousPipeline:
         assert len({st.io.in_shape for st in plan.stages}) > 1
         D, mb = 2, 4
         mbs = _mbs(topo, mb=mb)
-        mesh = jax.make_mesh((n_stages, D), ("stage", "data"))
+        mesh = make_pipeline_mesh((n_stages, D), ("stage", "data"))
         assert n_stages * D >= 4
         out = plan.run_pipelined(mbs, mesh=mesh, data_axis="data")
         np.testing.assert_array_equal(
@@ -136,7 +137,7 @@ class TestHeterogeneousPipeline:
         params = init_cnn(jax.random.PRNGKey(0), topo)
         plan = _compile(topo, params, PAPER_BITS[name], n_stages)
         mbs = _mbs(topo)
-        mesh = jax.make_mesh((n_stages,), ("stage",))
+        mesh = make_pipeline_mesh((n_stages,), ("stage",))
         out = plan.run_pipelined(mbs, mesh=mesh)
         np.testing.assert_array_equal(
             np.asarray(out), np.asarray(_seq_features(plan, mbs))
@@ -152,7 +153,7 @@ class TestHeterogeneousPipeline:
         params = init_cnn(jax.random.PRNGKey(0), HET4)
         plan = _compile(HET4, params, bits, n_stages)
         mbs = _mbs(HET4, m=5, mb=2)
-        mesh = jax.make_mesh((n_stages,), ("stage",))
+        mesh = make_pipeline_mesh((n_stages,), ("stage",))
         out = plan.run_pipelined(mbs, mesh=mesh)
         np.testing.assert_array_equal(
             np.asarray(out), np.asarray(_seq_features(plan, mbs))
@@ -167,7 +168,7 @@ class TestHeterogeneousPipeline:
         plan = _compile(topo, params, None, 3)
         D, mb = 2, 4
         mbs = _mbs(topo, m=3, mb=mb)
-        mesh = jax.make_mesh((3, D), ("stage", "data"))
+        mesh = make_pipeline_mesh((3, D), ("stage", "data"))
         out = plan.run_pipelined(mbs, mesh=mesh, data_axis="data")
         np.testing.assert_array_equal(
             np.asarray(out), np.asarray(_sharded_ref(plan, mbs, D))
@@ -177,7 +178,7 @@ class TestHeterogeneousPipeline:
         topo = ALL_TOPOLOGIES["lenet5"]
         params = init_cnn(jax.random.PRNGKey(0), topo)
         plan = _compile(topo, params, None, 2)
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = make_pipeline_mesh((4,), ("stage",))
         with pytest.raises(ValueError, match="mesh axis"):
             plan.run_pipelined(_mbs(topo), mesh=mesh)
 
@@ -185,7 +186,7 @@ class TestHeterogeneousPipeline:
         topo = ALL_TOPOLOGIES["cifar10"]
         params = init_cnn(jax.random.PRNGKey(0), topo)
         plan = _compile(topo, params, None, 3)
-        mesh = jax.make_mesh((3, 2), ("stage", "data"))
+        mesh = make_pipeline_mesh((3, 2), ("stage", "data"))
         with pytest.raises(ValueError, match="not divisible"):
             plan.run_pipelined(
                 _mbs(topo, mb=3), mesh=mesh, data_axis="data"
@@ -207,7 +208,7 @@ class TestEdgePaths:
         params = init_cnn(jax.random.PRNGKey(0), topo)
         plan = _compile(topo, params, bits, n_stages)
         mbs = _mbs(topo, m=3, mb=2)
-        mesh = jax.make_mesh((n_stages,), ("stage",))
+        mesh = make_pipeline_mesh((n_stages,), ("stage",))
         exact = plan.run_pipelined(mbs, mesh=mesh, edge_mode="exact")
         boxed = plan.run_pipelined(mbs, mesh=mesh, edge_mode="boxed")
         ref = np.asarray(_seq_features(plan, mbs))
@@ -223,7 +224,7 @@ class TestEdgePaths:
         params = init_cnn(jax.random.PRNGKey(0), topo)
         plan = _compile(topo, params, None, 3)
         mbs = _mbs(topo, m=n_microbatches, mb=2)
-        mesh = jax.make_mesh((3,), ("stage",))
+        mesh = make_pipeline_mesh((3,), ("stage",))
         serial = plan.run_pipelined(mbs, mesh=mesh, overlap=False)
         overlapped = plan.run_pipelined(mbs, mesh=mesh, overlap=True)
         ref = np.asarray(_seq_features(plan, mbs))
@@ -238,7 +239,7 @@ class TestEdgePaths:
         plan = _compile(topo, params, PAPER_BITS["svhn"], 3)
         D = 2
         mbs = _mbs(topo, m=4, mb=4)
-        mesh = jax.make_mesh((3, D), ("stage", "data"))
+        mesh = make_pipeline_mesh((3, D), ("stage", "data"))
         out = plan.run_pipelined(
             mbs, mesh=mesh, data_axis="data", overlap=True
         )
@@ -257,7 +258,7 @@ class TestEdgePaths:
         topo = ALL_TOPOLOGIES["cifar10"]
         params = init_cnn(jax.random.PRNGKey(0), topo)
         plan = _compile(topo, params, None, 3)
-        mesh = jax.make_mesh((3,), ("stage",))
+        mesh = make_pipeline_mesh((3,), ("stage",))
         auto = build_plan_pipeline(
             plan, mesh=mesh, cfg=PipelineConfig(3, 4)
         )
@@ -288,7 +289,7 @@ class TestEngineOnMesh:
         bits = PAPER_BITS["lenet5"] if quant == "quant" else None
         params = init_cnn(jax.random.PRNGKey(0), topo)
         plan = _compile(topo, params, bits, 2)
-        mesh = jax.make_mesh((2, 2), ("stage", "data"))
+        mesh = make_pipeline_mesh((2, 2), ("stage", "data"))
         eng = Engine(
             plan, microbatch=4, mesh=mesh, n_microbatches=3,
             data_axis="data",
@@ -319,7 +320,7 @@ class TestEngineOnMesh:
         }]
         tuning = autotune_pipeline(plan, 4, measurements=measured)
         assert tuning.source == "measured" and tuning.overlap
-        mesh = jax.make_mesh((2, 2), ("stage", "data"))
+        mesh = make_pipeline_mesh((2, 2), ("stage", "data"))
         eng = Engine(plan, mesh=mesh, data_axis="data", tuning=tuning)
         assert eng.group == 8 and eng.overlap
         assert eng._runner.edge_plan.mode == "boxed"
@@ -337,7 +338,7 @@ class TestEngineOnMesh:
         topo = ALL_TOPOLOGIES["lenet5"]
         params = init_cnn(jax.random.PRNGKey(0), topo)
         plan = _compile(topo, params, None, 2)
-        mesh = jax.make_mesh((2,), ("stage",))
+        mesh = make_pipeline_mesh((2,), ("stage",))
         eng = Engine(plan, microbatch=2, mesh=mesh, n_microbatches=2)
         x = jax.random.normal(jax.random.PRNGKey(4), (3, 28, 28, 1))
         out = eng.infer(x)
@@ -373,3 +374,26 @@ class TestMeshSuiteSubprocess:
             timeout=1800,
         )
         assert res.returncode == 0, (res.stdout + res.stderr)[-3000:]
+
+
+class TestPipelineMesh:
+    """Single-device checks of the mesh the pipeline accepts."""
+
+    def test_make_pipeline_mesh_axes_are_auto(self):
+        from jax.sharding import AxisType
+
+        mesh = make_pipeline_mesh((1, 1), ("stage", "data"))
+        assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+
+    def test_explicit_mesh_is_refused(self):
+        """``jax.make_mesh`` defaults to Explicit axes, on which the
+        final-stage selection raises deep inside JAX; the builder refuses
+        such a mesh up front and names the helper."""
+        from repro.core.dhm.pipeline import PipelineConfig, build_pipeline
+
+        mesh = jax.make_mesh((1,), ("stage",))
+        with pytest.raises(ValueError, match="make_pipeline_mesh"):
+            build_pipeline(
+                [lambda p, x: x], [{}], mesh=mesh,
+                cfg=PipelineConfig(1, 1), io_specs=None,
+            )
