@@ -1,0 +1,9 @@
+"""Host milliseconds per dispatched micro-batch in one phase of the
+Engine's flush, the device-to-host read of the logits: the Engine's
+``fetch_s`` counter over ``n_batches``, over the untraced part of the
+window."""
+from hostspans import per_batch_ms
+
+
+def read(ctx):
+    return per_batch_ms(ctx, "fetch_s")

@@ -29,6 +29,9 @@ as a compiler pipeline:
   fault-tolerant serving ``Engine`` (continuous batching with deadline
   SLOs, bounded-queue admission control, watchdog + retry + a graceful
   degradation ladder, structured per-request errors).
+- ``spans``: the ``Engine``'s span log — host phases, queue waits and
+  garbage collections in preallocated columns, off unless armed
+  (``Engine.start_spans``).
 - ``faults``: deterministic, seed-driven fault injection (delayed flush,
   dispatch errors, stalled collectives, NaN activations, device loss)
   wired through ``Engine(fault_plan=...)`` for the chaos suite; fault
@@ -67,6 +70,7 @@ from repro.core.dhm.engine import (
     Shed,
     run_pipelined,
 )
+from repro.core.dhm.spans import SpanLog
 from repro.core.dhm.multitenant import (
     CircuitBreaker,
     CircuitOpen,
@@ -143,6 +147,7 @@ __all__ = [
     "CircuitOpen",
     "Engine",
     "EngineStats",
+    "SpanLog",
     "FaultPlan",
     "FlusherWedged",
     "Router",

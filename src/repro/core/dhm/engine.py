@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.dhm import spans
 from repro.core.dhm.faults import FaultPlan, InjectedDeviceLoss
 from repro.core.dhm.pipeline import CollectiveTimeout, call_with_timeout
 
@@ -267,6 +268,12 @@ class Request:
 # serving rates, bounded so a long-lived engine never grows without limit.
 _LAT_WINDOW = 2048
 
+# Host phases of a flush, in the order of the Engine's per-phase seconds
+# (the EngineStats field each accumulates into).
+_PHASES = ("pack_s", "stage_s", "device_wait_s", "check_s", "fetch_s",
+           "complete_s")
+_PACK, _STAGE, _DEVICE_WAIT, _CHECK, _FETCH, _COMPLETE = range(len(_PHASES))
+
 
 def _percentile_ms(samples, q: float) -> float:
     """q-th percentile of a latency sample list, in milliseconds
@@ -289,7 +296,20 @@ class EngineStats:
     ``rung_latency_ms`` records p50/p99 **per execution-ladder rung**
     (over a bounded window of recent completions), so a demotion is
     visible as a latency regime change instead of vanishing into one
-    aggregate pool."""
+    aggregate pool.
+
+    The phase counters split a flush's host time: ``pack_s`` (numpy
+    concat and zero-pad), ``stage_s`` (queuing the asynchronous
+    host-to-device copy into a fresh buffer), ``device_wait_s`` (the
+    forward call through ``block_until_ready``, which holds the copy
+    itself and its layout transpose), ``check_s`` (the ``isfinite``
+    sync on the logits) and ``fetch_s`` (the device-to-host read of the
+    logits) lie inside ``busy_s``; what they leave of it is the take,
+    deadline filtering and the watchdog thread's start and join.
+    ``complete_s`` (the scatter to requests) follows each request's
+    ``done_at`` stamp and so lies outside ``busy_s``. ``n_slots`` counts
+    the frames dispatched, padding included, beside ``n_frames``
+    answered."""
 
     n_requests: int
     n_frames: int
@@ -308,9 +328,18 @@ class EngineStats:
     rung: str = ""
     # rung name -> {"p50_ms", "p99_ms", "n"} over the recent window.
     rung_latency_ms: dict = dataclasses.field(default_factory=dict)
+    pack_s: float = 0.0
+    stage_s: float = 0.0
+    device_wait_s: float = 0.0
+    check_s: float = 0.0
+    fetch_s: float = 0.0
+    complete_s: float = 0.0
+    n_slots: int = 0
 
     @property
-    def frames_per_s(self) -> float:
+    def frames_per_busy_s(self) -> float:
+        """Frames answered per second spent inside ``flush`` (not per
+        second of wall time)."""
         return self.n_frames / self.busy_s if self.busy_s > 0 else 0.0
 
     @property
@@ -324,8 +353,9 @@ class EngineStats:
     def summary(self) -> str:
         s = (
             f"{self.n_requests} requests / {self.n_frames} frames in "
-            f"{self.n_batches} micro-batches: {self.frames_per_s:.0f} "
-            f"frames/s, latency mean {self.mean_latency_s * 1e3:.2f} ms "
+            f"{self.n_batches} micro-batches: "
+            f"{self.frames_per_busy_s:.0f} frames/busy-s, latency mean "
+            f"{self.mean_latency_s * 1e3:.2f} ms "
             f"max {self.max_latency_s * 1e3:.2f} ms"
         )
         if self.n_errors:
@@ -504,7 +534,12 @@ class Engine:
         self._requests_base = 0
         self._frames = 0
         self._batches = 0
+        self._slots = 0
         self._busy_s = 0.0
+        self._phase_s = [0.0] * len(_PHASES)
+        self._n_flushes = 0
+        # The armed span log (start_spans / stop_spans), None while off.
+        self._spans: Optional[spans.SpanLog] = None
         # Running latency aggregates (a serving engine lives long — no
         # per-request history kept).
         self._lat_n = 0
@@ -872,78 +907,129 @@ class Engine:
                 x = jnp.full_like(x, jnp.nan)
         return self.plan.head_fn(x)
 
-    def _run_group(self, frames: jax.Array) -> jax.Array:
+    def _run_group(
+        self,
+        frames: jax.Array,
+        phases: Optional[list] = None,
+        parent: int = -1,
+        gid: int = 0,
+        real: Optional[int] = None,
+    ) -> jax.Array:
         """Run one exactly-``group``-sized batch through the active rung,
         blocked until ready: fault effects applied, watchdog timeout,
         bounded retry-with-backoff on transient failures, demotion on
         persistent ones. Raises :class:`LadderExhausted` when no rung can
         complete the batch, or :class:`_PoisonedBatch` when the inputs
-        themselves are non-finite (the flush isolates per request)."""
+        themselves are non-finite (the flush isolates per request).
+
+        Adds the stage, device-wait and check seconds to ``phases`` (the
+        flush's per-phase list); with spans armed, records a ``group``
+        span (``gid``, holding ``real`` frames) under the span row
+        ``parent``."""
+        if phases is None:
+            phases = [0.0] * len(_PHASES)
+        log = self._spans
+        grow = (
+            -1 if log is None
+            else log.begin(spans.GROUP, gid, parent, time.perf_counter())
+        )
         backoff = self.retry_backoff_s
         retries_left = self.max_retries
-        while True:
-            eff = (
-                self._faults.dispatch_effects(
-                    rung=self._rung_name, tenant=self.name
+        try:
+            while True:
+                eff = (
+                    self._faults.dispatch_effects(
+                        rung=self._rung_name, tenant=self.name
+                    )
+                    if self._faults is not None
+                    else None
                 )
-                if self._faults is not None
-                else None
-            )
 
-            def _attempt():
-                if eff is not None:
-                    if eff.stall_s:
-                        time.sleep(eff.stall_s)
-                    if eff.exc is not None:
-                        raise eff.exc
-                    if eff.corrupt_stage is not None:
-                        return jax.block_until_ready(
+                def _attempt():
+                    if eff is not None:
+                        if eff.stall_s:
+                            time.sleep(eff.stall_s)
+                        if eff.exc is not None:
+                            raise eff.exc
+                    t_a = time.perf_counter()
+                    if eff is not None and eff.corrupt_stage is not None:
+                        t_b = t_a
+                        out = jax.block_until_ready(
                             self._corrupted_forward(frames, eff.corrupt_stage)
                         )
-                return jax.block_until_ready(self._fwd(self._stage(frames)))
+                    else:
+                        staged = self._stage(frames)
+                        t_b = time.perf_counter()
+                        out = jax.block_until_ready(self._fwd(staged))
+                    t_c = time.perf_counter()
+                    if log is not None:
+                        log.add(spans.STAGE, 0, grow, t_a, t_b)
+                        log.add(spans.FORWARD, 0, grow, t_b, t_c)
+                    return out, t_b - t_a, t_c - t_b
 
-            try:
-                out = call_with_timeout(
-                    _attempt,
-                    timeout_s=self.dispatch_timeout_s,
-                    what=f"dispatch (rung {self._rung_name})",
-                )
-                with self._lock:
-                    self._batches += 1
-                if self.check_outputs and not bool(jnp.isfinite(out).all()):
-                    if not bool(np.isfinite(np.asarray(frames)).all()):
-                        raise _PoisonedBatch(
-                            "packed batch carries non-finite input frames"
-                        )
-                    raise _NonFiniteOutput(
-                        f"rung {self._rung_name} produced non-finite logits "
-                        "from finite inputs"
+                try:
+                    out, stage_s, wait_s = call_with_timeout(
+                        _attempt,
+                        timeout_s=self.dispatch_timeout_s,
+                        what=f"dispatch (rung {self._rung_name})",
                     )
-                return out
-            except _PoisonedBatch:
-                raise
-            except (InjectedDeviceLoss, CollectiveTimeout) as e:
-                # Not transient: a lost device or wedged collective will
-                # not heal on retry — demote off the rung immediately.
-                self._demote(e)
-                retries_left = self.max_retries
-                backoff = self.retry_backoff_s
-            except Exception as e:  # noqa: BLE001 — retry then demote
-                if retries_left > 0:
-                    retries_left -= 1
                     with self._lock:
-                        self._n_retries += 1
-                    _LOG.info(
-                        "dispatch failed on rung %r (%s); retrying in "
-                        "%.3fs (%d retries left)",
-                        self._rung_name, e, backoff, retries_left,
+                        self._batches += 1
+                        self._slots += self.group
+                    t_d = time.perf_counter()
+                    bad = self.check_outputs and not bool(
+                        jnp.isfinite(out).all()
                     )
-                    time.sleep(backoff)
-                    backoff *= 2
-                else:
+                    t_e = time.perf_counter()
+                    phases[_STAGE] += stage_s
+                    phases[_DEVICE_WAIT] += wait_s
+                    phases[_CHECK] += t_e - t_d
+                    if log is not None:
+                        log.add(spans.CHECK, 0, grow, t_d, t_e)
+                    if bad:
+                        if not bool(np.isfinite(np.asarray(frames)).all()):
+                            raise _PoisonedBatch(
+                                "packed batch carries non-finite input frames"
+                            )
+                        raise _NonFiniteOutput(
+                            f"rung {self._rung_name} produced non-finite "
+                            "logits from finite inputs"
+                        )
+                    return out
+                except _PoisonedBatch:
+                    raise
+                except (InjectedDeviceLoss, CollectiveTimeout) as e:
+                    # Not transient: a lost device or wedged collective will
+                    # not heal on retry — demote off the rung immediately.
                     self._demote(e)
                     retries_left = self.max_retries
                     backoff = self.retry_backoff_s
+                except Exception as e:  # noqa: BLE001 — retry then demote
+                    if retries_left > 0:
+                        retries_left -= 1
+                        with self._lock:
+                            self._n_retries += 1
+                        _LOG.info(
+                            "dispatch failed on rung %r (%s); retrying in "
+                            "%.3fs (%d retries left)",
+                            self._rung_name, e, backoff, retries_left,
+                        )
+                        t_r = time.perf_counter()
+                        time.sleep(backoff)
+                        if log is not None:
+                            log.add(spans.RETRY, 0, grow, t_r,
+                                    time.perf_counter())
+                        backoff *= 2
+                    else:
+                        self._demote(e)
+                        retries_left = self.max_retries
+                        backoff = self.retry_backoff_s
+        finally:
+            if log is not None:
+                log.end(
+                    grow, time.perf_counter(),
+                    self.group if real is None else real,
+                )
 
     # -- flushing -------------------------------------------------------------
 
@@ -988,9 +1074,17 @@ class Engine:
                     pending.append(r)
                     taken += r.n_frames
                 self._queue_frames -= taken
+            self._n_flushes += 1
+            flush_no = self._n_flushes
             self._cv.notify_all()
         n_taken = sum(r.n_frames for r in pending)
         t0 = time.perf_counter()
+        log = self._spans
+        frow = -1
+        if log is not None:
+            frow = log.begin(spans.FLUSH, flush_no, -1, t0)
+            for req in pending:
+                log.add(spans.QUEUED, req.index, frow, req.submitted_at, t0)
         live = []
         for req in pending:
             if req.deadline_at is not None and t0 > req.deadline_at:
@@ -1005,12 +1099,17 @@ class Engine:
             else:
                 live.append(req)
         if not live:
+            if log is not None:
+                log.end(frow, time.perf_counter())
             return n_taken
+        ph = [0.0] * len(_PHASES)
+        done = None
         try:
             # Pack on the HOST: the request count (and so the concat/pad
             # shapes) varies per flush, and eager jnp ops compile once per
             # distinct shape — numpy packing keeps the device path at the
             # one fixed group shape the jitted closure was compiled for.
+            t_p = time.perf_counter()
             frames = np.concatenate(
                 [np.asarray(r._frames) for r in live], axis=0
             )
@@ -1021,31 +1120,39 @@ class Engine:
                     [frames,
                      np.zeros((pad,) + self._frame_shape, frames.dtype)]
                 )
+            t_q = time.perf_counter()
+            ph[_PACK] = t_q - t_p
+            if log is not None:
+                log.add(spans.PACK, 0, frow, t_p, t_q)
             outs = []
             for start in range(0, frames.shape[0], self.group):
-                outs.append(
-                    np.asarray(
-                        self._run_group(frames[start : start + self.group])
-                    )
+                out = self._run_group(
+                    frames[start : start + self.group], ph, frow,
+                    start // self.group, min(self.group, n - start),
                 )
+                t_f = time.perf_counter()
+                outs.append(np.asarray(out))
+                # Drop the device logits before the scatter: releasing a
+                # device array can hand the interpreter to the callers the
+                # scatter wakes, and the flush loop would then take part of
+                # their resubmissions (a flush of padded groups).
+                del out
+                t_g = time.perf_counter()
+                ph[_FETCH] += t_g - t_f
+                if log is not None:
+                    log.add(spans.FETCH, 0, frow, t_f, t_g)
             logits = (
                 outs[0][:n] if len(outs) == 1
                 else np.concatenate(outs, axis=0)[:n]
             )
         except _PoisonedBatch:
-            self._isolate(live)
-            with self._lock:
-                self._busy_s += time.perf_counter() - t0
-            return n_taken
+            self._isolate(live, ph, frow)
         except LadderExhausted as e:
             for req in live:
                 self._fail(
                     req,
                     BatchFailed(f"request {req.index}: batch failed — {e}"),
                 )
-            with self._lock:
-                self._busy_s += time.perf_counter() - t0
-            return n_taken
         except Exception as e:  # noqa: BLE001 — never drop requests silently
             _LOG.exception("unexpected flush failure")
             for req in live:
@@ -1056,19 +1163,27 @@ class Engine:
                         f"{type(e).__name__}: {e}"
                     ),
                 )
-            with self._lock:
-                self._busy_s += time.perf_counter() - t0
-            return n_taken
-        done = time.perf_counter()
-        off = 0
-        for req in live:
-            self._complete(req, logits[off : off + req.n_frames], done)
-            off += req.n_frames
+        else:
+            done = time.perf_counter()
+            off = 0
+            for req in live:
+                self._complete(req, logits[off : off + req.n_frames], done)
+                off += req.n_frames
+        t_end = time.perf_counter()
+        if done is not None:
+            ph[_COMPLETE] = t_end - done
+        if log is not None:
+            if done is not None:
+                log.add(spans.COMPLETE, 0, frow, done, t_end)
+            log.end(frow, t_end, len(live))
         with self._lock:
-            self._busy_s += done - t0
+            # A failed flush counts until its requests have failed.
+            self._busy_s += (t_end if done is None else done) - t0
+            for i, s in enumerate(ph):
+                self._phase_s[i] += s
         return n_taken
 
-    def _isolate(self, reqs: list) -> None:
+    def _isolate(self, reqs: list, phases: list, parent: int = -1) -> None:
         """Rerun a poisoned batch one request at a time: invalid requests
         fail alone with :class:`InvalidRequest`, the rest recompute
         cleanly — one bad frame never takes down its batchmates."""
@@ -1091,9 +1206,11 @@ class Engine:
             try:
                 outs = []
                 for start in range(0, x.shape[0], self.group):
-                    outs.append(
-                        np.asarray(self._run_group(x[start : start + self.group]))
-                    )
+                    outs.append(np.asarray(self._run_group(
+                        x[start : start + self.group], phases, parent,
+                        start // self.group,
+                        min(self.group, req.n_frames - start),
+                    )))
                 logits = np.concatenate(outs, axis=0)[: req.n_frames]
             except (LadderExhausted, _PoisonedBatch) as e:
                 self._fail(
@@ -1182,7 +1299,11 @@ class Engine:
         while not self._stop.is_set():
             with self._cv:
                 if not self._queue:
+                    log = self._spans
+                    t_w = time.perf_counter() if log is not None else 0.0
                     self._cv.wait(timeout=interval)
+                    if log is not None:
+                        log.add(spans.WAIT, 0, -1, t_w, time.perf_counter(), 0)
                     continue
                 full = self._queue_frames >= self.group
                 ddl = min(
@@ -1206,13 +1327,41 @@ class Engine:
                 wait = interval - (now - last_flush)
                 if ddl is not None:
                     wait = min(wait, ddl - margin - now)
+                log = self._spans
                 with self._cv:
+                    t_w = time.perf_counter() if log is not None else 0.0
                     self._cv.wait(timeout=max(1e-4, wait))
+                    if log is not None:
+                        log.add(spans.WAIT, 0, -1, t_w, time.perf_counter(), 1)
         # Drain whatever arrived before the stop signal.
         try:
             self.flush()
         except Exception:  # noqa: BLE001
             _LOG.exception("final drain flush failed")
+
+    # -- spans ----------------------------------------------------------------
+
+    def start_spans(self, capacity: int) -> spans.SpanLog:
+        """Arm a fresh :class:`~repro.core.dhm.spans.SpanLog` of
+        ``capacity`` rows: from the next flush on, the Engine records its
+        host phases, queue waits, flush-loop waits and garbage
+        collections there until :meth:`stop_spans`. While no log is
+        armed, each span boundary costs one ``is None`` test."""
+        if self._spans is not None:
+            raise RuntimeError("a span log is already armed; stop_spans() first")
+        log = spans.SpanLog(capacity)
+        log.watch_gc()
+        self._spans = log
+        return log
+
+    def stop_spans(self) -> spans.SpanLog:
+        """Detach the armed span log and close it (its ``gc`` callback
+        removed, its counts fixed); returns it."""
+        log = self._spans
+        if log is None:
+            raise RuntimeError("no span log is armed")
+        self._spans = None
+        return log.close()
 
     # -- conveniences ----------------------------------------------------------
 
@@ -1229,7 +1378,9 @@ class Engine:
                 n_requests=self._requests - self._requests_base,
                 n_frames=self._frames,
                 n_batches=self._batches,
+                n_slots=self._slots,
                 busy_s=self._busy_s,
+                **dict(zip(_PHASES, self._phase_s)),
                 mean_latency_s=(
                     self._lat_sum / self._lat_n if self._lat_n else 0.0
                 ),
@@ -1262,7 +1413,9 @@ class Engine:
             self._requests_base = self._requests
             self._frames = 0
             self._batches = 0
+            self._slots = 0
             self._busy_s = 0.0
+            self._phase_s = [0.0] * len(_PHASES)
             self._lat_n = 0
             self._lat_sum = 0.0
             self._lat_max = 0.0

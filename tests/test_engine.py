@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.dhm import spans
 from repro.core.dhm.compiler import QuantSpec, compile_dhm
 from repro.core.dhm.engine import (
     DeadlineExceeded,
@@ -122,9 +123,9 @@ class TestEngineQueue:
         assert st.n_requests == 1
         assert st.n_frames == 6
         assert st.n_batches == 2  # 6 frames -> two 4-frame µbatches
-        assert st.frames_per_s > 0
+        assert st.frames_per_busy_s > 0
         assert st.max_latency_s >= st.mean_latency_s > 0
-        assert "frames/s" in st.summary()
+        assert "frames/busy-s" in st.summary()
 
     def test_flush_empty_queue_is_noop(self):
         _, plan = _plan("lenet5")
@@ -346,6 +347,136 @@ class TestStatsWindowAndStop:
         # The wedged flusher eventually wakes, finds nothing, and exits;
         # stop() is idempotent afterwards.
         eng.stop()
+
+
+class TestEngineSpans:
+    """The Engine's always-on phase counters and its span log, which
+    records nothing unless armed."""
+
+    INSIDE_BUSY = ("pack_s", "stage_s", "device_wait_s", "check_s", "fetch_s")
+
+    @staticmethod
+    def _by_kind(log, name):
+        cols = log.columns()
+        return np.flatnonzero(cols["kind"] == spans.KINDS.index(name))
+
+    def test_phase_counters_lie_inside_busy_and_reset(self):
+        topo, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4)
+        for seed in range(3):
+            eng.infer(_frames(topo, 6, seed=seed))
+        st = eng.stats()
+        phases = [getattr(st, f) for f in self.INSIDE_BUSY]
+        assert all(p >= 0 for p in phases) and st.complete_s >= 0
+        assert st.device_wait_s > 0
+        assert sum(phases) <= st.busy_s
+        eng.reset_stats()
+        st = eng.stats()
+        assert [getattr(st, f) for f in self.INSIDE_BUSY] == [0.0] * 5
+        assert st.complete_s == 0.0 and st.n_slots == 0
+
+    def test_slots_count_the_padding_of_a_flush(self):
+        topo, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4)
+        eng.submit(_frames(topo, 3))
+        eng.submit(_frames(topo, 2, seed=2))
+        eng.flush()  # 5 frames -> two 4-frame groups, 3 frames of padding
+        st = eng.stats()
+        assert st.n_frames == 5 and st.n_batches == 2
+        assert st.n_slots - st.n_frames == 3
+
+    def test_spans_nest_under_their_group_and_flush(self):
+        topo, plan = _plan("lenet5")
+        # The watchdog thread runs stage and forward: a timeout is set.
+        eng = Engine(plan, microbatch=4, dispatch_timeout_s=60.0)
+        log = eng.start_spans(4096)
+        reqs = [eng.submit(_frames(topo, 3, seed=i)) for i in range(3)]
+        eng.flush()
+        eng.infer(_frames(topo, 4, seed=9))
+        assert eng.stop_spans() is log and log.dropped == 0
+        cols = log.columns()
+        kind, parent = cols["kind"], cols["parent"]
+        flush, group = spans.KINDS.index("flush"), spans.KINDS.index("group")
+        children = [self._by_kind(log, k) for k in ("stage", "forward", "check")]
+        assert all(len(rows) == 4 for rows in children)  # 3 + 1 groups
+        for rows in children:
+            assert (kind[parent[rows]] == group).all()
+            assert (kind[parent[parent[rows]]] == flush).all()
+        groups = self._by_kind(log, "group")
+        assert sorted(cols["arg"][groups]) == [1, 4, 4, 4]  # real frames
+        assert (cols["end_ns"] >= cols["start_ns"]).all()
+        # One queued span per request, id = its index, under its flush.
+        queued = self._by_kind(log, "queued")
+        assert sorted(cols["id"][queued]) == [r.index for r in reqs] + [3]
+        assert (kind[parent[queued]] == flush).all()
+        fl = self._by_kind(log, "flush")
+        assert list(cols["id"][fl]) == [1, 2] and list(cols["arg"][fl]) == [3, 1]
+
+    def test_flush_loop_waits_and_collections_are_spans(self):
+        import gc
+
+        topo, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4, flush_interval_ms=2.0)
+        log = eng.start_spans(4096)
+        with eng:
+            eng.submit(_frames(topo, 2)).result(timeout=30)
+            time.sleep(0.05)
+            gc.collect()
+        eng.stop_spans()
+        assert len(self._by_kind(log, "wait")) > 0
+        collected = self._by_kind(log, "gc")
+        assert 2 in log.columns()["arg"][collected]
+
+    def test_past_capacity_spans_are_dropped(self):
+        topo, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4)
+        log = eng.start_spans(3)
+        for seed in range(3):
+            eng.infer(_frames(topo, 4, seed=seed))
+        eng.stop_spans()
+        assert log.n == 3 and log.dropped > 0
+        assert (log.kind >= 0).all()
+
+    def test_stop_removes_the_gc_callback(self):
+        import gc
+
+        _, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4)
+        before = len(gc.callbacks)
+        eng.start_spans(16)
+        assert len(gc.callbacks) == before + 1
+        eng.stop_spans()
+        assert len(gc.callbacks) == before
+        with pytest.raises(RuntimeError):
+            eng.stop_spans()
+
+    def test_nothing_is_recorded_while_unarmed(self):
+        topo, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4)
+        log = eng.start_spans(1024)
+        eng.infer(_frames(topo, 4))
+        eng.stop_spans()
+        n = log.n
+        assert n > 0
+        eng.infer(_frames(topo, 4, seed=2))
+        with eng:
+            eng.submit(_frames(topo, 2, seed=3)).result(timeout=30)
+        assert (log.kind[n:] == -1).all()
+        assert eng._spans is None
+
+    def test_recording_tracks_no_new_objects(self):
+        import gc
+
+        log = spans.SpanLog(20_000)
+        gc.collect()
+        before = len(gc.get_objects())
+        t = time.perf_counter()
+        for i in range(10_000):
+            log.add(spans.PACK, i, -1, t, t + 1e-6)
+        after = len(gc.get_objects())
+        log.close()
+        assert log.n == 10_000
+        assert after - before < 100
 
 
 class TestExtractedExecution:

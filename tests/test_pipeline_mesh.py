@@ -301,7 +301,7 @@ class TestEngineOnMesh:
             np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5
         )
         st = eng.stats()
-        assert st.n_frames == 12 and st.frames_per_s > 0
+        assert st.n_frames == 12 and st.frames_per_busy_s > 0
 
     def test_engine_tuned_config(self):
         """A PipelineTuning overrides the engine's pipeline knobs
