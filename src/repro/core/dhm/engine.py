@@ -298,18 +298,24 @@ class EngineStats:
     visible as a latency regime change instead of vanishing into one
     aggregate pool.
 
-    The phase counters split a flush's host time: ``pack_s`` (numpy
-    concat and zero-pad), ``stage_s`` (queuing the asynchronous
-    host-to-device copy into a fresh buffer), ``device_wait_s`` (the
-    forward call through ``block_until_ready``, which holds the copy
-    itself and its layout transpose), ``check_s`` (the ``isfinite``
-    sync on the logits) and ``fetch_s`` (the device-to-host read of the
-    logits) lie inside ``busy_s``; what they leave of it is the take,
-    deadline filtering and the watchdog thread's start and join.
-    ``complete_s`` (the scatter to requests) follows each request's
-    ``done_at`` stamp and so lies outside ``busy_s``. ``n_slots`` counts
-    the frames dispatched, padding included, beside ``n_frames``
-    answered."""
+    The phase counters split a flush's host time. A flush runs its
+    micro-batches two deep: it launches group k+1 (stage and closure
+    call, without waiting) before it finishes group k (waits for it,
+    fetches its logits and checks them on the host copy).
+    ``pack_s`` (numpy concat and zero-pad), ``stage_s`` (queuing the
+    asynchronous host-to-device copy into a fresh buffer),
+    ``device_wait_s`` (the blocking wait in a group's finish, which holds
+    whatever of the copy, its layout transpose and the program is still
+    to run), ``fetch_s`` (the device-to-host read of the logits) and
+    ``check_s`` (``isfinite`` on that host copy) lie inside ``busy_s``;
+    what they leave of it is the take, deadline filtering, the closure
+    calls and the watchdog thread's start and join. ``complete_s`` (the
+    scatter to requests) follows each request's ``done_at`` stamp and so
+    lies outside ``busy_s``. ``n_slots`` counts the frames dispatched,
+    padding included, beside ``n_frames`` answered. ``n_overlapped``
+    counts the micro-batches (of ``n_batches``) launched while an
+    earlier one of the same flush was unfinished: ``n_overlapped /
+    n_batches`` is (G-1)/G over flushes of G groups."""
 
     n_requests: int
     n_frames: int
@@ -335,6 +341,7 @@ class EngineStats:
     fetch_s: float = 0.0
     complete_s: float = 0.0
     n_slots: int = 0
+    n_overlapped: int = 0
 
     @property
     def frames_per_busy_s(self) -> float:
@@ -376,6 +383,22 @@ class EngineStats:
                 f"p99 {lat['p99_ms']:.2f} ms ({lat['n']} samples)"
             )
         return s
+
+
+@dataclasses.dataclass
+class _Flight:
+    """One launched micro-batch: its logits on the device until the
+    finish fetches them (then None), the injected stall the finish
+    sleeps, its ``group`` span row, when the closure was called (the
+    ``forward`` span's start), its stage seconds, and whether an earlier
+    group of its flush was unfinished at its launch."""
+
+    out: Optional[jax.Array]
+    stall_s: float
+    row: int
+    t_launch: float
+    stage_s: float
+    overlapped: bool
 
 
 # ---------------------------------------------------------------------------
@@ -535,6 +558,7 @@ class Engine:
         self._frames = 0
         self._batches = 0
         self._slots = 0
+        self._overlapped = 0
         self._busy_s = 0.0
         self._phase_s = [0.0] * len(_PHASES)
         self._n_flushes = 0
@@ -890,10 +914,10 @@ class Engine:
     def _stage(self, batch: jax.Array) -> jax.Array:
         """Stage a packed micro-batch into a fresh buffer the closure can
         consume. The copy is what makes donation safe (the caller's arrays
-        stay valid and a failed dispatch can restage for its retry);
-        because the closure is dispatched asynchronously, the flush loop
-        stages batch k+1 while batch k's donated buffer is still being
-        computed on — the double-buffered serving path."""
+        stay valid and a failed dispatch can restage for its retry). The
+        copy is queued asynchronously, so within a flush batch k+1 is
+        staged and launched while batch k is still being computed on —
+        the double-buffered serving path (:meth:`_run_groups`)."""
         return jnp.array(batch, copy=True)
 
     def _corrupted_forward(self, frames: jax.Array, stage: int) -> jax.Array:
@@ -907,25 +931,118 @@ class Engine:
                 x = jnp.full_like(x, jnp.nan)
         return self.plan.head_fn(x)
 
+    def _launch(
+        self, frames: np.ndarray, row: int = -1, overlapped: bool = False
+    ) -> _Flight:
+        """Launch one exactly-``group``-sized batch on the active rung
+        without waiting for it: draw its fault effects (an injected
+        exception is raised here), stage it, and call the rung's closure
+        (or the corrupted forward under an injected NaN). Runs on the
+        calling thread; :meth:`_finish` waits for the result. ``row`` is
+        the group's span row; ``overlapped`` says an earlier group of the
+        flush is still unfinished."""
+        stall_s, corrupt = 0.0, None
+        if self._faults is not None:
+            eff = self._faults.dispatch_effects(
+                rung=self._rung_name, tenant=self.name
+            )
+            if eff.exc is not None:
+                raise eff.exc
+            stall_s, corrupt = eff.stall_s, eff.corrupt_stage
+        t_a = time.perf_counter()
+        if corrupt is not None:
+            t_b = t_a
+            out = self._corrupted_forward(frames, corrupt)
+        else:
+            staged = self._stage(frames)
+            t_b = time.perf_counter()
+            out = self._fwd(staged)
+        log = self._spans
+        if log is not None:
+            log.add(spans.STAGE, 0, row, t_a, t_b)
+        return _Flight(out, stall_s, row, t_b, t_b - t_a, overlapped)
+
+    def _finish(
+        self, fl: _Flight, frames: np.ndarray, phases: list
+    ) -> np.ndarray:
+        """Wait for a launched group under the watchdog
+        (``dispatch_timeout_s``; an injected stall is slept first), fetch
+        its logits to the host and check them there. Returns the host
+        logits; the device array is dropped before this returns. Raises
+        :class:`CollectiveTimeout`, :class:`_PoisonedBatch` (the inputs
+        themselves are non-finite) or :class:`_NonFiniteOutput`."""
+
+        def _wait():
+            try:
+                if fl.stall_s:
+                    time.sleep(fl.stall_s)
+                t_c = time.perf_counter()
+                jax.block_until_ready(fl.out)
+                t_d = time.perf_counter()
+                host = np.asarray(fl.out)
+            finally:
+                # Drop the device logits here, before the scatter:
+                # releasing a device array can hand the interpreter to the
+                # callers the scatter wakes, and the flush loop would then
+                # take part of their resubmissions (a flush of padded
+                # groups).
+                fl.out = None
+            t_e = time.perf_counter()
+            ok = not self.check_outputs or bool(np.isfinite(host).all())
+            return host, ok, (t_c, t_d, t_e, time.perf_counter())
+
+        host, ok, (t_c, t_d, t_e, t_f) = call_with_timeout(
+            _wait,
+            timeout_s=self.dispatch_timeout_s,
+            what=f"dispatch (rung {self._rung_name})",
+        )
+        with self._lock:
+            self._batches += 1
+            self._slots += self.group
+            self._overlapped += fl.overlapped
+        phases[_STAGE] += fl.stage_s
+        phases[_DEVICE_WAIT] += t_d - t_c
+        phases[_FETCH] += t_e - t_d
+        phases[_CHECK] += t_f - t_e
+        log = self._spans
+        if log is not None:
+            log.add(spans.FORWARD, 0, fl.row, fl.t_launch, t_d)
+            log.add(spans.FETCH, 0, fl.row, t_d, t_e)
+            log.add(spans.CHECK, 0, fl.row, t_e, t_f)
+        if not ok:
+            if not bool(np.isfinite(np.asarray(frames)).all()):
+                raise _PoisonedBatch(
+                    "packed batch carries non-finite input frames"
+                )
+            raise _NonFiniteOutput(
+                f"rung {self._rung_name} produced non-finite logits from "
+                "finite inputs"
+            )
+        return host
+
     def _run_group(
         self,
-        frames: jax.Array,
+        frames: np.ndarray,
         phases: Optional[list] = None,
         parent: int = -1,
         gid: int = 0,
         real: Optional[int] = None,
-    ) -> jax.Array:
+        failed: Optional[Exception] = None,
+    ) -> np.ndarray:
         """Run one exactly-``group``-sized batch through the active rung,
-        blocked until ready: fault effects applied, watchdog timeout,
-        bounded retry-with-backoff on transient failures, demotion on
-        persistent ones. Raises :class:`LadderExhausted` when no rung can
-        complete the batch, or :class:`_PoisonedBatch` when the inputs
-        themselves are non-finite (the flush isolates per request).
+        one launch and finish at a time: fault effects applied, watchdog
+        timeout, bounded retry-with-backoff on transient failures,
+        demotion on persistent ones. Returns the logits on the host.
+        Raises :class:`LadderExhausted` when no rung can complete the
+        batch, or :class:`_PoisonedBatch` when the inputs themselves are
+        non-finite (the flush isolates per request). ``failed`` is the
+        error of an attempt already made on this batch by the pipelined
+        flush; it counts as the first attempt.
 
-        Adds the stage, device-wait and check seconds to ``phases`` (the
-        flush's per-phase list); with spans armed, records a ``group``
-        span (``gid``, holding ``real`` frames) under the span row
-        ``parent``."""
+        Adds the stage, device-wait, fetch and check seconds to
+        ``phases`` (the flush's per-phase list); with spans armed,
+        records a ``group`` span (``gid``, holding ``real`` frames) under
+        the span row ``parent``."""
         if phases is None:
             phases = [0.0] * len(_PHASES)
         log = self._spans
@@ -937,65 +1054,13 @@ class Engine:
         retries_left = self.max_retries
         try:
             while True:
-                eff = (
-                    self._faults.dispatch_effects(
-                        rung=self._rung_name, tenant=self.name
-                    )
-                    if self._faults is not None
-                    else None
-                )
-
-                def _attempt():
-                    if eff is not None:
-                        if eff.stall_s:
-                            time.sleep(eff.stall_s)
-                        if eff.exc is not None:
-                            raise eff.exc
-                    t_a = time.perf_counter()
-                    if eff is not None and eff.corrupt_stage is not None:
-                        t_b = t_a
-                        out = jax.block_until_ready(
-                            self._corrupted_forward(frames, eff.corrupt_stage)
-                        )
-                    else:
-                        staged = self._stage(frames)
-                        t_b = time.perf_counter()
-                        out = jax.block_until_ready(self._fwd(staged))
-                    t_c = time.perf_counter()
-                    if log is not None:
-                        log.add(spans.STAGE, 0, grow, t_a, t_b)
-                        log.add(spans.FORWARD, 0, grow, t_b, t_c)
-                    return out, t_b - t_a, t_c - t_b
-
                 try:
-                    out, stage_s, wait_s = call_with_timeout(
-                        _attempt,
-                        timeout_s=self.dispatch_timeout_s,
-                        what=f"dispatch (rung {self._rung_name})",
+                    if failed is not None:
+                        err, failed = failed, None
+                        raise err
+                    return self._finish(
+                        self._launch(frames, grow), frames, phases
                     )
-                    with self._lock:
-                        self._batches += 1
-                        self._slots += self.group
-                    t_d = time.perf_counter()
-                    bad = self.check_outputs and not bool(
-                        jnp.isfinite(out).all()
-                    )
-                    t_e = time.perf_counter()
-                    phases[_STAGE] += stage_s
-                    phases[_DEVICE_WAIT] += wait_s
-                    phases[_CHECK] += t_e - t_d
-                    if log is not None:
-                        log.add(spans.CHECK, 0, grow, t_d, t_e)
-                    if bad:
-                        if not bool(np.isfinite(np.asarray(frames)).all()):
-                            raise _PoisonedBatch(
-                                "packed batch carries non-finite input frames"
-                            )
-                        raise _NonFiniteOutput(
-                            f"rung {self._rung_name} produced non-finite "
-                            "logits from finite inputs"
-                        )
-                    return out
                 except _PoisonedBatch:
                     raise
                 except (InjectedDeviceLoss, CollectiveTimeout) as e:
@@ -1030,6 +1095,72 @@ class Engine:
                     grow, time.perf_counter(),
                     self.group if real is None else real,
                 )
+
+    def _run_groups(
+        self, frames: np.ndarray, n: int, phases: list, parent: int
+    ) -> list:
+        """Run a flush's packed ``frames`` (``n`` of them real) group by
+        group, two deep: launch group k+1, then finish group k, so the
+        device computes k+1 while the host waits for, fetches and checks
+        k. At most two groups are in flight. After a failure in the
+        launch or the finish of group k, a launched k+1 is dropped, and
+        group k and the rest of the flush run through :meth:`_run_group`,
+        the failed attempt counting as group k's first. Returns the host
+        logits of each group, in order."""
+        g = self.group
+        count = frames.shape[0] // g
+        log = self._spans
+
+        def end(fl, arg):
+            if log is not None:
+                log.end(fl.row, time.perf_counter(), arg)
+
+        def launch(k):
+            row = (
+                -1 if log is None
+                else log.begin(spans.GROUP, k, parent, time.perf_counter())
+            )
+            try:
+                return self._launch(frames[k * g : (k + 1) * g], row, k > 0)
+            except Exception:
+                if log is not None:
+                    log.end(row, time.perf_counter(), 0)
+                raise
+
+        outs = []
+        k, failed, ahead = 0, None, None
+        try:
+            ahead = launch(0)
+        except Exception as e:  # noqa: BLE001 — rerun through _run_group
+            failed = e
+        while failed is None and k < count:
+            nxt = None
+            if k + 1 < count:
+                try:
+                    nxt = launch(k + 1)
+                except Exception as e:  # noqa: BLE001 — after finishing k
+                    failed = e
+            try:
+                outs.append(
+                    self._finish(ahead, frames[k * g : (k + 1) * g], phases)
+                )
+            except Exception as e:  # noqa: BLE001 — rerun through _run_group
+                end(ahead, 0)
+                if nxt is not None:
+                    nxt.out = None  # dropped unfinished
+                    end(nxt, 0)
+                if isinstance(e, _PoisonedBatch):
+                    raise
+                failed = e
+                break
+            end(ahead, min(g, n - k * g))
+            ahead, k = nxt, k + 1
+        for j in range(k, count):
+            outs.append(self._run_group(
+                frames[j * g : (j + 1) * g], phases, parent, j,
+                min(g, n - j * g), failed if j == k else None,
+            ))
+        return outs
 
     # -- flushing -------------------------------------------------------------
 
@@ -1124,23 +1255,7 @@ class Engine:
             ph[_PACK] = t_q - t_p
             if log is not None:
                 log.add(spans.PACK, 0, frow, t_p, t_q)
-            outs = []
-            for start in range(0, frames.shape[0], self.group):
-                out = self._run_group(
-                    frames[start : start + self.group], ph, frow,
-                    start // self.group, min(self.group, n - start),
-                )
-                t_f = time.perf_counter()
-                outs.append(np.asarray(out))
-                # Drop the device logits before the scatter: releasing a
-                # device array can hand the interpreter to the callers the
-                # scatter wakes, and the flush loop would then take part of
-                # their resubmissions (a flush of padded groups).
-                del out
-                t_g = time.perf_counter()
-                ph[_FETCH] += t_g - t_f
-                if log is not None:
-                    log.add(spans.FETCH, 0, frow, t_f, t_g)
+            outs = self._run_groups(frames, n, ph, frow)
             logits = (
                 outs[0][:n] if len(outs) == 1
                 else np.concatenate(outs, axis=0)[:n]
@@ -1379,6 +1494,7 @@ class Engine:
                 n_frames=self._frames,
                 n_batches=self._batches,
                 n_slots=self._slots,
+                n_overlapped=self._overlapped,
                 busy_s=self._busy_s,
                 **dict(zip(_PHASES, self._phase_s)),
                 mean_latency_s=(
@@ -1414,6 +1530,7 @@ class Engine:
             self._frames = 0
             self._batches = 0
             self._slots = 0
+            self._overlapped = 0
             self._busy_s = 0.0
             self._phase_s = [0.0] * len(_PHASES)
             self._lat_n = 0

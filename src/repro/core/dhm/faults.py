@@ -13,10 +13,16 @@ consults it at two hook points:
 
 - ``on_flush()`` — before a flush packs its batch (:class:`DelayedFlush`
   sleeps here, so deadline handling can be exercised);
-- ``dispatch_effects(rung=...)`` — before each micro-batch dispatch;
-  returns the :class:`DispatchEffects` to apply *inside* the timed
-  dispatch call (a pre-dispatch stall, a raised error, or a
-  NaN-corruption of the activations at a chosen stage boundary).
+- ``dispatch_effects(rung=...)`` — at each micro-batch's launch;
+  returns the :class:`DispatchEffects` for that attempt (a raised error
+  at the launch, a NaN-corruption of the activations at a chosen stage
+  boundary, or a stall slept inside the watchdog-timed wait).
+
+A flush launches micro-batch k+1 before it finishes k. When k fails, the
+launched k+1 is dropped and rerun, so that launch has drawn one dispatch
+event of its own: a window counted in dispatch events then lands one
+event earlier in the batches after a failure than it would if each
+batch ran alone.
 
 Each fault fires on a trigger window of dispatch/flush events
 (``at``-th event onwards, for ``times`` events; ``times=None`` = forever)
@@ -128,8 +134,9 @@ class DeviceLoss(Fault):
 
 @dataclasses.dataclass(frozen=True)
 class DispatchEffects:
-    """What the fault plan injects into ONE dispatch attempt (applied by
-    the engine inside the timed dispatch callable, in this order)."""
+    """What the fault plan injects into ONE dispatch attempt: ``exc`` is
+    raised at the launch, ``corrupt_stage`` replaces the forward, and
+    ``stall_s`` is slept inside the watchdog-timed wait for the result."""
 
     stall_s: float = 0.0
     exc: Optional[BaseException] = None

@@ -8,11 +8,11 @@ none), ``start_ns``, ``end_ns`` and ``arg``. Times are
 
 Recording a span writes into the columns and creates no Python object
 the garbage collector tracks, so an armed log does not bring on
-collections of its own. Rows are handed out by an atomic counter, so the
-flush thread and the dispatch watchdog thread may record at once without
-a lock; spans past ``capacity`` are counted in ``dropped`` and not
-written. While armed, the log also records each garbage collection as a
-``gc`` span (through ``gc.callbacks``).
+collections of its own. Rows are handed out by an atomic counter, so
+several threads may record at once without a lock; spans past
+``capacity`` are counted in ``dropped`` and not written. While armed,
+the log also records each garbage collection as a ``gc`` span (through
+``gc.callbacks``).
 
 Span kinds, with the parent each is recorded under:
 
@@ -21,15 +21,21 @@ Span kinds, with the parent each is recorded under:
   ``submitted_at`` to the take.
 - ``pack`` (parent: the flush): numpy concat and zero-pad.
 - ``group`` (id: group number within its flush; parent: the flush; arg:
-  real frames): one micro-batch dispatch, retries included. Its time
-  outside its children is the watchdog thread's cost.
-- ``stage``, ``forward`` (parent: the group; on the watchdog thread):
-  queuing the asynchronous host-to-device copy, and the forward through
-  ``block_until_ready``, which holds the copy itself.
-- ``check`` (parent: the group): the ``isfinite`` sync on the logits.
+  real frames, 0 for a launch dropped or handed to the serial retry): one
+  micro-batch from its launch to the end of its finish, retries
+  included. A flush keeps two groups in flight (it launches group k+1
+  before it finishes group k), so consecutive groups' spans overlap. A
+  group's time outside its children is what follows its check, the
+  watchdog thread's join.
+- ``stage`` (parent: the group): queuing the asynchronous host-to-device
+  copy, in the launch.
+- ``forward`` (parent: the group): from the closure's call in the launch
+  to the logits being ready in the finish. It holds the copy itself, the
+  next group's launch and the watchdog thread's start.
+- ``fetch``, ``check`` (parent: the group): the device-to-host read of
+  the logits, and ``isfinite`` on that host copy.
 - ``retry`` (parent: the group): one backoff sleep.
-- ``fetch``, ``complete`` (parent: the flush): the device-to-host read of
-  the logits, and the scatter to requests.
+- ``complete`` (parent: the flush): the scatter to requests.
 - ``wait`` (arg: 0 queue empty, 1 not full and not due): the flush
   loop's wait on its condition.
 - ``gc`` (arg: generation): one garbage collection.

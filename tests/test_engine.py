@@ -20,7 +20,12 @@ from repro.core.dhm.engine import (
     forward,
     plan_jitted_forward,
 )
-from repro.core.dhm.faults import DelayedFlush, FaultPlan
+from repro.core.dhm.faults import (
+    DelayedFlush,
+    FaultPlan,
+    NaNActivation,
+    StalledDispatch,
+)
 from repro.core.dhm.pipeline import StageIOSpec, derive_io_specs
 from repro.models.cnn import ALL_TOPOLOGIES, LENET5, init_cnn
 
@@ -157,12 +162,16 @@ class TestEngineQueue:
 class TestDeadlines:
     def test_background_flusher_dispatches_for_deadline(self):
         """With a huge flush interval, only the request's deadline can
-        trigger dispatch — the flusher must wake for it."""
+        trigger dispatch — the flusher must wake for it. The margin is
+        wider than a loaded host's late wake (a thread waiting for the
+        interpreter lock wakes milliseconds past its timeout), so the
+        deadline has not passed at the take."""
         topo, plan = _plan("lenet5")
         with Engine(
-            plan, microbatch=8, auto_flush=True, flush_interval_ms=5000.0
+            plan, microbatch=8, auto_flush=True, flush_interval_ms=5000.0,
+            deadline_margin_ms=250.0,
         ) as eng:
-            req = eng.submit(_frames(topo, 1), deadline_ms=100.0)
+            req = eng.submit(_frames(topo, 1), deadline_ms=1000.0)
             out = req.result(timeout=10.0)
         assert out.shape == (1, topo.n_classes)
         assert req.ok and req.latency_s < 2.0  # nowhere near the interval
@@ -477,6 +486,101 @@ class TestEngineSpans:
         log.close()
         assert log.n == 10_000
         assert after - before < 100
+
+
+class TestPipelinedFlush:
+    """A flush of several micro-batches launches group k+1 before it
+    finishes group k, and checks the logits on their host copy."""
+
+    @staticmethod
+    def _split(x, sizes):
+        edges = np.cumsum((0,) + sizes)
+        return [x[a:b] for a, b in zip(edges[:-1], edges[1:])]
+
+    def test_multi_group_flush_is_bit_equal_to_groups_run_alone(self):
+        topo, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4)
+        x = np.asarray(_frames(topo, 14))
+        reqs = [eng.submit(p) for p in self._split(x, (5, 1, 7, 1))]
+        eng.flush()  # 14 frames -> four groups in one flush
+        assert eng.stats().n_batches == 4
+        got = np.concatenate([r.result() for r in reqs])
+        alone = np.concatenate(
+            [np.asarray(eng.infer(x[s : s + 4])) for s in range(0, 14, 4)]
+        )
+        np.testing.assert_array_equal(got, alone)
+
+    @pytest.mark.parametrize("n_groups", [1, 3])
+    def test_overlapped_counts_groups_launched_behind_another(self, n_groups):
+        topo, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4)
+        eng.infer(_frames(topo, 4 * n_groups))
+        st = eng.stats()
+        assert st.n_batches == n_groups
+        assert st.n_overlapped == n_groups - 1
+        eng.reset_stats()
+        assert eng.stats().n_overlapped == 0
+
+    def test_next_group_is_staged_before_this_one_is_fetched(self):
+        topo, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4, dispatch_timeout_s=60.0)
+        log = eng.start_spans(4096)
+        eng.infer(_frames(topo, 12))  # three groups in one flush
+        eng.stop_spans()
+        cols = log.columns()
+        kind, parent = cols["kind"], cols["parent"]
+
+        def by_group(name, col):
+            rows = np.flatnonzero(kind == spans.KINDS.index(name))
+            return {int(cols["id"][parent[r]]): cols[col][r] for r in rows}
+
+        stage_start = by_group("stage", "start_ns")
+        fetch_end = by_group("fetch", "end_ns")
+        assert sorted(stage_start) == sorted(fetch_end) == [0, 1, 2]
+        for k in (0, 1):
+            assert stage_start[k + 1] < fetch_end[k]
+
+    def test_nan_in_the_middle_group_retries_it_alone(self):
+        topo, plan = _plan("lenet5")
+        faults = FaultPlan([NaNActivation(at=1, times=1, stage=0)])
+        eng = Engine(
+            plan, microbatch=4, retry_backoff_s=1e-4, fault_plan=faults
+        )
+        x = np.asarray(_frames(topo, 12))
+        reqs = [eng.submit(p) for p in self._split(x, (3, 5, 4))]
+        eng.flush()  # three groups; the second one's logits are NaN
+        assert all(r.done and r.ok for r in reqs)
+        clean = Engine(plan, microbatch=4)
+        want = [np.asarray(clean.infer(p)) for p in self._split(x, (3, 5, 4))]
+        for r, w in zip(reqs, want):
+            np.testing.assert_array_equal(np.asarray(r.result()), w)
+        st = eng.stats()
+        assert st.n_ok == 3 and st.n_frames == 12
+        assert st.n_retries == 1 and st.n_demotions == 0
+        # Launches 0, 1 and 2, the rerun of group 1, then group 2 again:
+        # the dropped launch of group 2 drew an event of its own.
+        assert faults.n_dispatch_events == 5
+
+    def test_stall_in_a_pipelined_group_times_out_and_demotes(self):
+        topo, plan = _plan("lenet5")
+        eng = Engine(
+            plan,
+            microbatch=4,
+            dispatch_timeout_s=0.2,
+            retry_backoff_s=1e-4,
+            fault_plan=FaultPlan(
+                [StalledDispatch(at=1, times=1, stall_s=5.0, rung="fused")]
+            ),
+        )
+        x = _frames(topo, 12)
+        got = eng.infer(x)  # returns promptly: watchdog + demotion
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(plan(x)), rtol=1e-4, atol=1e-5
+        )
+        st = eng.stats()
+        assert st.n_demotions == 1 and eng.rung == "per_layer"
+        assert st.n_retries == 0
+        assert "did not complete" in eng.demotions[0]["reason"]
 
 
 class TestExtractedExecution:
