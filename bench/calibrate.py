@@ -25,13 +25,15 @@ import run
 
 
 def control_numbers(cfg: dict, traffic: dict, seed: int) -> dict:
+    family, topology = registry.family(cfg.get("family")), cfg["topology"]
     rs = inputs.streams(seed)
-    params = inputs.make_params(cfg["topology"], rs["weights"])
+    params = family.make_params(topology, rs["weights"])
     pool = inputs.frame_pool(
-        cfg["topology"], cfg["frame_grid_bits"], traffic["pool_frames"], rs["frames"]
+        family.frame_shape(topology), cfg["frame_grid_bits"], traffic["pool_frames"],
+        rs["frames"],
     )
-    ref = reference.logits(params, pool, cfg["topology"], **cfg["reference"])
-    ctl = reference.logits(params, pool, cfg["topology"], **cfg["control"])
+    ref = reference.logits(params, pool, family.forward, topology, **cfg["reference"])
+    ctl = reference.logits(params, pool, family.forward, topology, **cfg["control"])
     return check.numbers(ctl, ref)
 
 

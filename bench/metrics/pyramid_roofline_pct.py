@@ -4,9 +4,9 @@ Its events are the trace ops named ``%stream_conv_pyramid_pallas...`` (the
 custom call the Pallas kernel becomes); each processes as many frames as
 the leading dimension of its output shape. For the frames of the calls
 that start in the window, the least time is ``max(ops / peak, bytes /
-bandwidth)`` (``costs.roofline_s``, with ``costs.conv_ops`` and
-``costs.conv_bytes``), and the share is that over the kernel's summed
-device time. Which bound applied goes to standard error."""
+bandwidth)`` (``costs.roofline_s``, with the family's ``conv_ops`` and
+``conv_bytes``), and the share is that over the kernel's summed device
+time. Which bound applied goes to standard error."""
 import re
 import sys
 
@@ -29,8 +29,8 @@ def read(ctx):
         if not m:
             return None
         n = int(m.group(1))
-        ops += n * costs.conv_ops(topo)
-        n_bytes += costs.conv_bytes(topo, n, ctx.cfg["kernel_act_bytes"])
+        ops += n * ctx.family.conv_ops(topo)
+        n_bytes += ctx.family.conv_bytes(topo, n, ctx.cfg["kernel_act_bytes"])
         busy += (end - start) / 1e9
     t_roof, bound = costs.roofline_s(ops, n_bytes, ctx.device_kind, ctx.cfg)
     print(f"pyramid_roofline bound={bound} calls={len(evs)} "

@@ -1,6 +1,10 @@
 """Find what ``BENCHMARK.json`` names, by name, in files of their own.
 
 - a configuration: the file its ``configs`` entry gives;
+- the family that knows a configuration's model: ``families/<family>.py``,
+  named by the configuration's ``family``, a module with ``make_params``,
+  ``frame_shape``, ``forward``, ``frame_ops``, ``system`` and
+  ``kernel_calls`` (see ``families/cnn_chain.py``);
 - a traffic mix: ``traffic/<traffic>.json``, data;
 - the loop that drives a mix: ``traffic/<loop>.py``, named by the mix's
   ``loop``, a module with ``threads(load, t0, t_end)`` (see ``loadgen``);
@@ -9,8 +13,8 @@
   name before its first dot: ``dispatch_ms.bulk`` and
   ``dispatch_ms.cameras`` share ``metrics/dispatch_ms.py``.
 
-Adding a configuration, a mix, a loop or a metric is adding a file and an
-entry; nothing here changes.
+Adding a configuration, a model family, a mix, a loop or a metric is adding
+a file and an entry; nothing here changes.
 """
 from __future__ import annotations
 
@@ -61,6 +65,15 @@ def traffic_loop(name: str, bench: pathlib.Path = BENCH):
     """The module ``traffic/<name>.py`` that drives mixes whose ``loop``
     is ``name``."""
     return _module(bench / "traffic" / f"{name}.py", "loop")
+
+
+def family(name, bench: pathlib.Path = BENCH):
+    """The module ``families/<name>.py``, ``name`` being a configuration's
+    ``family``. There is no default: a configuration that names none, or
+    names no such file, is an error."""
+    if not name:
+        raise KeyError("the configuration names no family")
+    return _module(bench / "families" / f"{name}.py", "family")
 
 
 def metric_reader(name: str, bench: pathlib.Path = BENCH):

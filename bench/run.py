@@ -3,17 +3,20 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell (``BENCHMARK.json``'s ``workloads``) is one configuration under one
-traffic mix. The run makes the weights and frames from the seed, compiles
-the configuration with the program's ``compile_dhm``, serves it behind the
+traffic mix. Everything that knows the configuration's model is its
+family's (``families/<family>.py``, found by name). The run makes the
+weights and frames from the seed, has the family compile the
+configuration with the program (``system``), serves the plan behind the
 program's ``Engine`` with the background flusher, drives the traffic mix
-for ``--seconds``, then checks every answer against the plain reference.
+for ``--seconds``, then checks every answer against the plain reference,
+the family's ``forward``.
 
 It refuses to measure anything but the DHM path on a TPU: no TPU, fewer
 chips than the cell asks for, a compiled forward whose Mosaic kernels
-(``tpu_custom_call``) do not match the plan's fusion groups, an Engine that
-served on a rung other than ``fused`` or recorded a demotion, or a
-compilation inside the window each end the run with a non-zero exit and no
-result line.
+(``tpu_custom_call``) are not as many as the family's ``kernel_calls``
+says, an Engine that served on a rung other than ``fused`` or recorded a
+demotion, or a compilation inside the window each end the run with a
+non-zero exit and no result line.
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics. With
 ``--trace 1`` it carries the per-layer metrics: the profiler records the
@@ -118,28 +121,20 @@ class CompileCounter:
             self.n += 1
 
 
-def _system(cfg: dict, params):
-    """The system under test: the configuration compiled by the program."""
-    from repro.core.dhm import QuantSpec, compile_dhm
-    from repro.models.cnn import CNNTopology, ConvLayerSpec
-
-    t = cfg["topology"]
-    topo = CNNTopology(
-        name=cfg["name"],
-        input_hw=tuple(t["input_hw"]),
-        input_channels=t["input_channels"],
-        conv_layers=tuple(ConvLayerSpec(**layer) for layer in t["conv_layers"]),
-        fc_dims=tuple(t["fc_dims"]),
-        n_classes=t["n_classes"],
-    )
-    return compile_dhm(topo, params, quant=QuantSpec(**cfg["quant"]))
-
-
-def _count_kernels(jax, plan, group: int, topology: dict) -> int:
-    h, w = topology["input_hw"]
-    x = jax.ShapeDtypeStruct((group, h, w, topology["input_channels"]), np.float32)
+def _check_kernels(plan, family, x) -> None:
+    """The plan's forward, compiled for the frames ``x`` (a shape), holds
+    as many Mosaic kernels as the family's ``kernel_calls`` says."""
     text = plan.jitted_forward(donate=True).lower(x).compile().as_text()
-    return text.count('custom_call_target="tpu_custom_call"')
+    n_kernels = text.count('custom_call_target="tpu_custom_call"')
+    n_groups = len(plan.fusion_groups)
+    print(f"tpu_custom_call={n_kernels} fusion_groups={n_groups}", flush=True)
+    want = family.kernel_calls(plan)
+    if n_kernels != want:
+        raise RunFailed(
+            f"{n_kernels} tpu_custom_call in the compiled forward, where the "
+            f"family {family.__name__} expects {want} for {n_groups} fusion "
+            f"groups: not the DHM kernel path"
+        )
 
 
 def _check_rung(engine) -> None:
@@ -178,7 +173,7 @@ def _profile(jax, out_dir, until: float, window_mark) -> str:
     return found[0]
 
 
-def _compare(sent, pool, params, cfg) -> tuple:
+def _compare(sent, pool, params, cfg, family) -> tuple:
     """Reference logits for the pool frames the answered requests carry,
     and the compared numbers."""
     ok = [s for s in sent if s.ok]
@@ -187,7 +182,8 @@ def _compare(sent, pool, params, cfg) -> tuple:
     idx = np.concatenate([np.arange(s.first, s.first + s.n) for s in ok])
     served = np.concatenate([np.asarray(s.logits).reshape(s.n, -1) for s in ok])
     used, inv = np.unique(idx, return_inverse=True)
-    ref = reference.logits(params, pool[used], cfg["topology"], **cfg["reference"])
+    ref = reference.logits(params, pool[used], family.forward, cfg["topology"],
+                           **cfg["reference"])
     return check.numbers(served, ref[inv])
 
 
@@ -205,6 +201,7 @@ def run(argv=None, *, chip_checks: bool = True, traffic_overrides=None) -> dict:
     cfg = registry.config(bm, cell["config"])
     traffic = {**registry.traffic(cell["traffic"]), **(traffic_overrides or {})}
     topology = cfg["topology"]
+    family = registry.family(cfg.get("family"))
 
     jax = _jax()
     devices = jax.devices()
@@ -217,9 +214,10 @@ def run(argv=None, *, chip_checks: bool = True, traffic_overrides=None) -> dict:
     marks = [("start_jax", time.perf_counter())]
 
     rs = inputs.streams(args.seed)
-    params = inputs.make_params(topology, rs["weights"])
+    params = family.make_params(topology, rs["weights"])
+    frame_shape = family.frame_shape(topology)
     pool = inputs.frame_pool(
-        topology, cfg["frame_grid_bits"], traffic["pool_frames"], rs["frames"]
+        frame_shape, cfg["frame_grid_bits"], traffic["pool_frames"], rs["frames"]
     )
     starts = loadgen.request_starts(traffic, rs["traffic"])
     jax.block_until_ready(params)
@@ -227,18 +225,13 @@ def run(argv=None, *, chip_checks: bool = True, traffic_overrides=None) -> dict:
 
     from repro.core.dhm import Engine
 
-    plan = _system(cfg, params)
+    plan = family.system(cfg, params)
     marks.append(("compile_dhm", time.perf_counter()))
-    group = traffic["engine"]["microbatch"]
     if chip_checks:
-        n_kernels = _count_kernels(jax, plan, group, topology)
-        n_groups = len(plan.fusion_groups)
-        print(f"tpu_custom_call={n_kernels} fusion_groups={n_groups}", flush=True)
-        if n_kernels != n_groups:
-            raise RunFailed(
-                f"{n_kernels} tpu_custom_call in the compiled forward for "
-                f"{n_groups} fusion groups: not the DHM kernel path"
-            )
+        group = traffic["engine"]["microbatch"]
+        _check_kernels(
+            plan, family, jax.ShapeDtypeStruct((group, *frame_shape), np.float32)
+        )
     marks.append(("kernel_count", time.perf_counter()))
     engine = Engine(plan, **traffic["engine"])
     engine.start()
@@ -295,7 +288,7 @@ def run(argv=None, *, chip_checks: bool = True, traffic_overrides=None) -> dict:
 
     sent = load.sent
     ctx = Context(
-        cell=cell, cfg=cfg, traffic=traffic, seconds=args.seconds,
+        cell=cell, cfg=cfg, family=family, traffic=traffic, seconds=args.seconds,
         t0=t0, t_end=t_end, t_split=t_split, setup_s=setup_s, sent=sent,
         host_stats=host_stats, device_kind=devices[0].device_kind,
         trace=tracereduce.read(trace_path) if trace_path else None,
@@ -308,7 +301,7 @@ def run(argv=None, *, chip_checks: bool = True, traffic_overrides=None) -> dict:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
     unanswered = sum(1 for s in sent if s.seen is None)
-    nums = _compare(sent, pool, params, cfg)
+    nums = _compare(sent, pool, params, cfg, family)
     nums["unanswered"] = unanswered
     correct, checks = check.verdict(nums, cfg["limits"])
     device = {

@@ -1,10 +1,13 @@
-"""Operations and bytes from shapes, and the peaks table."""
+"""Operations and bytes from shapes (the chain family's), and the peaks
+table."""
 import json
 
 import pytest
 
 import costs
 import registry
+
+chain = registry.family("cnn_chain")
 
 LENET5 = {
     "input_hw": [28, 28],
@@ -25,9 +28,9 @@ def _cifar10():
 
 def test_cifar10_ops_per_frame():
     topo = _cifar10()
-    assert costs.conv_ops(topo) == 24_576_000
-    assert costs.head_ops(topo) == 132_352
-    assert costs.frame_ops(topo) == 24_708_352
+    assert chain.conv_ops(topo) == 24_576_000
+    assert chain.head_ops(topo) == 132_352
+    assert chain.frame_ops(topo) == 24_708_352
 
 
 def test_both_cifar10_configs_share_the_topology():
@@ -39,10 +42,10 @@ def test_both_cifar10_configs_share_the_topology():
 
 def test_lenet5_valid_convs_match_the_paper_workload():
     # Paper Table 4: 3.8 Mop for the LeNet5 feature extractor.
-    assert costs.conv_shapes(LENET5) == [(1, 20, 5, 24, 24), (20, 50, 5, 8, 8)]
-    assert costs.conv_ops(LENET5) == 3_776_000
-    assert costs.feature_shape(LENET5) == (4, 4, 50)
-    assert costs.head_ops(LENET5) == 2 * (800 * 500 + 500 * 10)
+    assert chain.conv_shapes(LENET5) == [(1, 20, 5, 24, 24), (20, 50, 5, 8, 8)]
+    assert chain.conv_ops(LENET5) == 3_776_000
+    assert chain.feature_shape(LENET5) == (4, 4, 50)
+    assert chain.head_ops(LENET5) == 2 * (800 * 500 + 500 * 10)
 
 
 @pytest.mark.parametrize("act_bytes", [1, 4])
@@ -50,7 +53,7 @@ def test_conv_bytes(act_bytes):
     topo = _cifar10()
     weights = (75 * 32 + 800 * 32 + 800 * 64) * act_bytes + (32 + 32 + 64) * 4
     per_frame = 32 * 32 * 3 * act_bytes + 4 * 4 * 64 * 4
-    assert costs.conv_bytes(topo, 256, act_bytes) == 256 * per_frame + weights
+    assert chain.conv_bytes(topo, 256, act_bytes) == 256 * per_frame + weights
 
 
 def test_roofline_picks_the_larger_bound():
@@ -93,6 +96,6 @@ def test_shapes_and_ops_agree_with_the_program_topologies(name):
         "fc_dims": list(t.fc_dims),
         "n_classes": t.n_classes,
     }
-    assert costs.conv_shapes(topology) == [tuple(s) for s in t.conv_shapes()]
-    assert costs.feature_shape(topology) == tuple(t.feature_shape())
-    assert costs.conv_ops(topology) == t.feature_extractor_ops()
+    assert chain.conv_shapes(topology) == [tuple(s) for s in t.conv_shapes()]
+    assert chain.feature_shape(topology) == tuple(t.feature_shape())
+    assert chain.conv_ops(topology) == t.feature_extractor_ops()

@@ -1,16 +1,18 @@
-"""The benchmark's plain reference agrees with the program's own oracle,
-``cnn_apply_reference``, at a small size, and the control (one precision
-step below the configuration) fails the configuration's limits."""
+"""The benchmark's plain reference, the chain family's forward, agrees
+with the program's own oracle, ``cnn_apply_reference``, at a small size,
+and the control (one precision step below the configuration) fails the
+configuration's limits."""
 import json
 
 import numpy as np
 import pytest
 
 import check
-import costs
 import inputs
 import reference
 import registry
+
+chain = registry.family("cnn_chain")
 
 SMALL = {
     "input_hw": [12, 12],
@@ -42,9 +44,9 @@ def _program_reference(params, frames, bits):
 @pytest.mark.parametrize("bits", [None, 6])
 def test_matches_the_program_oracle(bits):
     rs = inputs.streams(2**33 + 7)
-    params = inputs.make_params(SMALL, rs["weights"])
-    frames = inputs.frame_pool(SMALL, 6, 24, rs["frames"])
-    got = reference.logits(params, frames, SMALL, fake_quant_bits=bits)
+    params = chain.make_params(SMALL, rs["weights"])
+    frames = inputs.frame_pool(chain.frame_shape(SMALL), 6, 24, rs["frames"])
+    got = reference.logits(params, frames, chain.forward, SMALL, fake_quant_bits=bits)
     want = _program_reference(params, frames, bits)
     scale = np.abs(want).max()
     if bits is None:
@@ -57,22 +59,26 @@ def test_matches_the_program_oracle(bits):
 
 def test_bfloat16_operands_round_each_product_input():
     rs = inputs.streams(11)
-    params = inputs.make_params(SMALL, rs["weights"])
-    frames = inputs.frame_pool(SMALL, 6, 8, rs["frames"])
-    exact = reference.logits(params, frames, SMALL)
-    rounded = reference.logits(params, frames, SMALL, dot_operands="bfloat16")
+    params = chain.make_params(SMALL, rs["weights"])
+    frames = inputs.frame_pool(chain.frame_shape(SMALL), 6, 8, rs["frames"])
+    exact = reference.logits(params, frames, chain.forward, SMALL)
+    rounded = reference.logits(
+        params, frames, chain.forward, SMALL, dot_operands="bfloat16"
+    )
     gap = np.abs(exact - rounded).max() / np.abs(exact).max()
     assert 1e-5 < gap < 2.0**-5
 
 
 def test_blocks_cover_every_frame():
     rs = inputs.streams(3)
-    params = inputs.make_params(SMALL, rs["weights"])
-    frames = inputs.frame_pool(SMALL, 6, reference.BLOCK + 3, rs["frames"])
-    whole = reference.logits(params, frames, SMALL)
+    params = chain.make_params(SMALL, rs["weights"])
+    frames = inputs.frame_pool(
+        chain.frame_shape(SMALL), 6, reference.BLOCK + 3, rs["frames"]
+    )
+    whole = reference.logits(params, frames, chain.forward, SMALL)
     assert whole.shape == (reference.BLOCK + 3, 10)
     np.testing.assert_array_equal(
-        whole[-3:], reference.logits(params, frames[-3:], SMALL)
+        whole[-3:], reference.logits(params, frames[-3:], chain.forward, SMALL)
     )
 
 
@@ -84,10 +90,13 @@ def test_control_fails_the_limits(config):
     bm = registry.benchmark()
     cfg = registry.config(bm, config)
     rs = inputs.streams(2**31 + 99)
-    params = inputs.make_params(cfg["topology"], rs["weights"])
-    frames = inputs.frame_pool(cfg["topology"], cfg["frame_grid_bits"], 32, rs["frames"])
-    ref = reference.logits(params, frames, cfg["topology"], **cfg["reference"])
-    ctl = reference.logits(params, frames, cfg["topology"], **cfg["control"])
+    topo = cfg["topology"]
+    params = chain.make_params(topo, rs["weights"])
+    frames = inputs.frame_pool(
+        chain.frame_shape(topo), cfg["frame_grid_bits"], 32, rs["frames"]
+    )
+    ref = reference.logits(params, frames, chain.forward, topo, **cfg["reference"])
+    ctl = reference.logits(params, frames, chain.forward, topo, **cfg["control"])
     nums = {**check.numbers(ctl, ref), "unanswered": 0}
     correct, checks = check.verdict(nums, cfg["limits"])
     assert not correct, checks
@@ -96,8 +105,8 @@ def test_control_fails_the_limits(config):
 def test_inputs_repeat_from_the_seed():
     a, b = inputs.streams(2**40 + 1), inputs.streams(2**40 + 1)
     np.testing.assert_array_equal(a["weights"], b["weights"])
-    fa = inputs.frame_pool(SMALL, 6, 4, a["frames"])
-    fb = inputs.frame_pool(SMALL, 6, 4, b["frames"])
+    fa = inputs.frame_pool(chain.frame_shape(SMALL), 6, 4, a["frames"])
+    fb = inputs.frame_pool(chain.frame_shape(SMALL), 6, 4, b["frames"])
     np.testing.assert_array_equal(fa, fb)
     assert np.all(np.abs(fa * 16 - np.round(fa * 16)) == 0)
     assert fa.min() >= -2.0 and fa.max() <= 1.9375
@@ -152,9 +161,11 @@ def test_matches_the_program_oracle_on_strides_and_activations(name, bits):
 
     topology = {"overlap": OVERLAP, "strided": STRIDED}[name]
     rs = inputs.streams(2**35 + 3)
-    params = inputs.make_params(topology, rs["weights"])
-    frames = inputs.frame_pool(topology, 6, 16, rs["frames"])
-    got = reference.logits(params, frames, topology, fake_quant_bits=bits)
+    params = chain.make_params(topology, rs["weights"])
+    frames = inputs.frame_pool(chain.frame_shape(topology), 6, 16, rs["frames"])
+    got = reference.logits(
+        params, frames, chain.forward, topology, fake_quant_bits=bits
+    )
     with jax.default_matmul_precision("highest"):
         want = np.asarray(cnn_apply_reference(
             params, _program_topology(topology), frames,
@@ -171,14 +182,14 @@ def test_a_layer_the_reference_does_not_implement_is_refused(layer_change):
     topology = json.loads(json.dumps(SMALL))
     topology["conv_layers"][0].update(layer_change)
     rs = inputs.streams(5)
-    params = inputs.make_params(SMALL, rs["weights"])
-    frames = inputs.frame_pool(SMALL, 6, 8, rs["frames"])
+    params = chain.make_params(SMALL, rs["weights"])
+    frames = inputs.frame_pool(chain.frame_shape(SMALL), 6, 8, rs["frames"])
     with pytest.raises(ValueError, match="conv layer 0"):
-        reference.logits(params, frames, topology)
+        reference.logits(params, frames, chain.forward, topology)
 
 
 def test_a_layer_without_its_activation_is_refused():
     topology = json.loads(json.dumps(SMALL))
     del topology["conv_layers"][1]["act"]
     with pytest.raises(ValueError, match="missing"):
-        costs.conv_ops(topology)
+        chain.conv_ops(topology)

@@ -110,6 +110,45 @@ def test_broken_timed_path_is_not_correct(cpu_run, monkeypatch, fault):
     assert not out["correct"], out["checks"]
 
 
+@pytest.mark.parametrize("family_says, passes", [(0, True), (None, False)])
+def test_the_kernel_check_asks_the_family(capsys, family_says, passes):
+    """The compiled forward's Mosaic kernels are held to the family's
+    ``kernel_calls``, not to the plan's fusion groups. On the CPU the
+    plan's fusion group compiles to no kernel: a family whose
+    ``kernel_calls`` says 0 gets through, and the chain's, one kernel per
+    group, is refused."""
+    import types
+
+    import jax
+
+    import inputs
+    import run
+
+    chain = registry.family("cnn_chain")
+    cfg = registry.config(registry.benchmark(), "cifar10_int8")
+    cfg["topology"] = {
+        "input_hw": [12, 12], "input_channels": 3,
+        "conv_layers": [{"n_out": 8, "kernel": 5, "padding": "SAME", "pool": 2,
+                         "act": "tanh"}],
+        "fc_dims": [16], "n_classes": 10,
+    }
+    params = chain.make_params(cfg["topology"], inputs.streams(1)["weights"])
+    plan = chain.system(cfg, params)
+    x = jax.ShapeDtypeStruct((8, *chain.frame_shape(cfg["topology"])), np.float32)
+    family = chain
+    if family_says is not None:
+        family = types.ModuleType("no_kernel_family")
+        family.kernel_calls = lambda plan: family_says
+    n_groups = len(plan.fusion_groups)
+    assert n_groups >= 1
+    if passes:
+        run._check_kernels(plan, family, x)
+    else:
+        with pytest.raises(run.RunFailed, match=f"expects {n_groups}"):
+            run._check_kernels(plan, family, x)
+    assert f"tpu_custom_call=0 fusion_groups={n_groups}" in capsys.readouterr().out
+
+
 def test_percentile_is_nearest_rank():
     from pct import nearest_rank
 

@@ -99,6 +99,7 @@ def test_metric_readers_on_the_synthetic_trace(reduced):
         trace = reduced
         device_kind = "TPU v5 lite"
         cfg = registry.config(bm, "cifar10_int8")
+        family = registry.family(cfg["family"])
 
     idle = registry.metric_reader("device_idle_pct.bulk").read(Ctx)
     assert idle == pytest.approx(100.0 * (1 - 45 / 98))
