@@ -104,10 +104,13 @@ def _finite_params(ctx):
     reach a serving rung."""
     import jax.numpy as jnp
 
+    import jax
+
     out = []
     for li, p in enumerate(ctx.plan.conv_params):
-        for k, v in p.items():
+        for path, v in jax.tree_util.tree_leaves_with_path(p):
             if not bool(jnp.isfinite(v).all()):
+                k = "/".join(str(getattr(e, "key", e)) for e in path)
                 out.append(ctx.error(
                     "V301",
                     f"conv layer {li} parameter {k!r} contains non-finite "
@@ -210,6 +213,30 @@ def _head_io(ctx):
     return []
 
 
+@invariant("V306", name="blocks-intact", scope="plan")
+def _blocks_intact(ctx):
+    """No stage and no fusion group cuts a residual basic block: the
+    layers of every block (a ``join`` layer and the ones before it) lie
+    in one stage and one fusion group, so every skip edge stays inside
+    one kernel and the stage graph stays a chain."""
+    from repro.core.dhm.fusion import layer_units
+
+    plan = ctx.plan
+    layers = plan.topo.conv_layers
+    out = []
+    runs = [("stage", st.index, st.conv_layers) for st in plan.stages]
+    runs += [
+        ("fusion group", gi, g.layers)
+        for gi, g in enumerate(plan.fusion_groups)
+    ]
+    for kind, i, run in runs:
+        try:
+            layer_units(layers, run)
+        except ValueError as e:
+            out.append(ctx.error("V306", f"{kind} {i}: {e}"))
+    return out
+
+
 @invariant("V305", name="serving-surface", scope="plan")
 def _serving_surface(ctx):
     """The plan exposes the full surface the serving layer consumes —
@@ -254,17 +281,19 @@ def _one_dot_per_layer(ctx):
     """The traced feature extractor contains exactly one ``dot_general``
     per conv layer — the paper's one-MACC-array-per-actor mapping; a
     kernel that decomposes into per-tap matmuls (the seed's 25-dot
-    lowering) fails here."""
+    lowering) fails here. A projection shortcut is a conv of its own and
+    brings one more."""
     plan = ctx.plan
     if plan.backend not in _PALLAS_BACKENDS:
         return []
     n_conv = sum(len(st.conv_layers) for st in plan.stages)
+    n_conv += sum(1 for p in plan.conv_params if "proj" in p)
     got = count_primitive(ctx.features_jaxpr(), "dot_general")
     if got != n_conv:
         return [ctx.error(
             "V001",
-            f"feature trace has {got} dot_general eqns for {n_conv} conv "
-            "layers — expected exactly one per layer",
+            f"feature trace has {got} dot_general eqns for {n_conv} convs "
+            "(projections included) — expected exactly one per conv",
         )]
     return []
 
@@ -470,9 +499,11 @@ def _group_budget(ctx):
 
 @invariant("V202", name="cost-model-consistent", scope="resource")
 def _cost_model_consistent(ctx):
-    """Each group's recorded working set equals what ``fusion.py`` /
-    ``halo.py`` cost today for the same layers and block_rows — a stale
-    or hand-edited plan cannot smuggle in an outdated cost."""
+    """Each fused group's recorded working set equals what ``fusion.py``
+    / ``halo.py`` cost today for the same layers and block_rows — a
+    stale or hand-edited plan cannot smuggle in an outdated cost. A
+    single-layer group records none (its kernel is row-blocked, not a
+    pyramid) and is not re-costed."""
     from repro.core.dhm.fusion import (
         group_working_set,
         group_working_set_breakdown,
@@ -483,6 +514,8 @@ def _cost_model_consistent(ctx):
     elem_bytes = plan_elem_bytes(plan.quant)
     out = []
     for gi, g in enumerate(plan.fusion_groups):
+        if not g.fused:
+            continue  # a single-layer kernel: no pyramid working set
         try:
             want = group_working_set(
                 plan.topo, g.layers, block_rows=g.block_rows,
@@ -525,6 +558,8 @@ def _traced_working_set(ctx):
         return []  # V002 reports the mismatch
     out = []
     for gi, (eqn, g) in enumerate(zip(calls, groups)):
+        if not g.fused:
+            continue  # a single-layer kernel: no pyramid working set
         operands = sum(aval_bytes(v.aval) for v in eqn.invars)
         widest = 0
         for sub in iter_eqns(eqn.params.get("jaxpr", [])):
@@ -557,6 +592,8 @@ def _int8_slab_costing(ctx):
         return []
     out = []
     for gi, g in enumerate(plan.fusion_groups):
+        if not g.fused:
+            continue  # a single-layer kernel: no pyramid working set
         try:
             want_int8 = group_working_set(
                 plan.topo, g.layers, block_rows=g.block_rows, elem_bytes=1

@@ -14,14 +14,16 @@ Lowering stages:
    checked against the fused-epilogue vocabulary at compile time, so a
    typo'd ``act="rleu"`` raises here with the valid options, not as an
    opaque KeyError deep inside a kernel trace.
-2. **Expand** the CNN description into the paper-granularity dataflow
-   process network (``cnn_to_dpn``): one conv engine per (map, channel),
-   neuron sums, activation and pool actors, line buffers sized by the
-   fixed-point width of the quantization spec.
-3. **Partition** the actor graph into ``n_stages`` contiguous stages with
-   the exact min-max DP mapper, costed from the actor FLOP payloads — the
+2. **Cost** each conv layer as the paper-granularity dataflow process
+   network would (``graph.conv_layer_flops``: the FLOPs of one conv
+   engine per (map, channel), neuron sums, activation, pool and join
+   actors, in closed form). The actor graph itself (``cnn_to_dpn``) is
+   built only when a consumer reads ``CompiledDHM.graph``.
+3. **Partition** the layers into ``n_stages`` contiguous stages with
+   the exact min-max DP mapper, costed from those FLOPs — the
    critical-actor balancing the FPGA gets from its clock, solved here as a
-   linear-partition problem.
+   linear-partition problem. A residual net's basic block (``join``) is
+   one unit: no stage boundary falls inside it.
 4. **Fuse** each stage's layer run into maximal cross-layer fusion groups
    under a VMEM budget (``repro.core.dhm.fusion``): a group of consecutive
    conv layers is streamed through ONE fused pyramid kernel with all
@@ -57,13 +59,15 @@ import jax.numpy as jnp
 
 from repro.core.dhm.fusion import (
     DEFAULT_VMEM_BUDGET,
+    layer_units,
     plan_fusion_groups,
 )
-from repro.core.dhm.graph import DataflowGraph, cnn_to_dpn
+from repro.core.dhm.graph import DataflowGraph, cnn_to_dpn, conv_layer_flops
 from repro.core.dhm.mapping import StageAssignment, partition_stages
 from repro.core.dhm.pipeline import StageIOSpec
 from repro.kernels.backends import DEFAULT_BACKEND, validate_backend
 from repro.kernels.stream_conv.epilogue import ACTS, normalize_pool
+from repro.kernels.stream_conv.halo import BLOCK_SPAN, JOINS
 
 PADDINGS = ("SAME", "VALID")
 
@@ -194,12 +198,13 @@ def _spec_fields(spec) -> dict:
         pool=spec.pool,
         pool_stride=getattr(spec, "pool_stride", None),
         stride=getattr(spec, "stride", 1),
+        pool_pad=getattr(spec, "pool_pad", 0),
     )
 
 
 def _validate_layer(
     where: str, *, padding: str, act: str, pool: int,
-    pool_stride: int | None = None, stride: int = 1,
+    pool_stride: int | None = None, stride: int = 1, pool_pad: int = 0,
 ) -> None:
     """Compile-time validation of the layer vocabulary — a typo raises
     here with the options listed, not as a trace-time KeyError."""
@@ -217,16 +222,56 @@ def _validate_layer(
         raise ValueError(
             f"{where}: unknown padding {padding!r}; expected one of {PADDINGS}"
         )
+    pw, _ = normalize_pool(pool, pool_stride)
+    if not isinstance(pool_pad, int) or pool_pad < 0 or 2 * pool_pad > pw:
+        raise ValueError(
+            f"{where}: pool_pad must be an int in [0, window // 2] "
+            f"(-inf padding of a {pw}x{pw} pool), got {pool_pad!r}"
+        )
+
+
+def _validate_joins(topo) -> None:
+    """Residual joins close basic blocks of ``BLOCK_SPAN`` layers that do
+    not overlap or pool; an identity join needs the block's input shape."""
+    layers = topo.conv_layers
+    for j, spec in enumerate(layers):
+        join = getattr(spec, "join", "none")
+        if join not in JOINS:
+            raise ValueError(
+                f"{topo.name} conv layer {j}: unknown join {join!r}; "
+                f"expected one of {JOINS}"
+            )
+        if join == "none":
+            continue
+        where = f"{topo.name} conv layer {j} ({join} join)"
+        first = j - BLOCK_SPAN + 1
+        if first < 0:
+            raise ValueError(f"{where}: its block starts before layer 0")
+        for i in range(first, j + 1):
+            if i < j and getattr(layers[i], "join", "none") != "none":
+                raise ValueError(f"{where}: overlaps the block closed at {i}")
+            if layers[i].pool:
+                raise ValueError(f"{where}: layer {i} of the block pools")
+        stride = 1
+        for i in range(first, j + 1):
+            stride *= getattr(layers[i], "stride", 1)
+        c_in = topo.input_channels if first == 0 else layers[first - 1].n_out
+        if join == "identity" and (stride != 1 or c_in != spec.n_out):
+            raise ValueError(
+                f"{where}: the block changes the shape (stride {stride}, "
+                f"{c_in} -> {spec.n_out} channels); use a project join"
+            )
 
 
 def validate_topology(topo) -> None:
     """Validate every conv layer of a CNNTopology at compile time: the
-    layer vocabulary, and (when the topology exposes shape methods) that
-    every layer keeps positive spatial dims — a pool window larger than
-    its conv output raises here, instead of silently emitting a
-    zero-sized frame."""
+    layer vocabulary, the residual blocks, and (when the topology exposes
+    shape methods) that every layer keeps positive spatial dims — a pool
+    window larger than its conv output raises here, instead of silently
+    emitting a zero-sized frame."""
     for li, spec in enumerate(topo.conv_layers):
         _validate_layer(f"{topo.name} conv layer {li}", **_spec_fields(spec))
+    _validate_joins(topo)
     if not hasattr(topo, "input_shape"):
         return
     h, w = topo.input_shape
@@ -251,28 +296,33 @@ def validate_topology(topo) -> None:
 @functools.lru_cache(maxsize=64)
 def _cached_dpn(topo, bits: int) -> DataflowGraph:
     """CNNTopology is a frozen (hashable) dataclass, so the actor-graph
-    expansion — thousands of actors for CIFAR-sized nets — is built once
-    per (topology, bit-width), not once per trace."""
+    expansion — thousands of actors for CIFAR-sized nets, over a million
+    for ResNet-18 — is built once per (topology, bit-width), and only for
+    a consumer that reads ``CompiledDHM.graph``."""
     return cnn_to_dpn(topo, bits=bits)
 
 
-def _conv_layer_costs(graph: DataflowGraph, n_conv: int) -> list:
-    """Per-conv-layer FLOP cost summed from the actor payloads (conv layer
-    i owns DPN topological layer i + 1; layer 0 is the source)."""
-    by_layer: dict = {}
-    for a in graph.actors:
-        by_layer[a.layer] = by_layer.get(a.layer, 0.0) + a.flops
-    return [by_layer.get(i + 1, 0.0) for i in range(n_conv)]
-
-
 @functools.lru_cache(maxsize=256)
-def _cached_layout(topo, bits: int, n_stages: int) -> StageAssignment:
-    """Cost aggregation (a Python walk over thousands of actors) + the DP
-    partition depend only on (topology, bit-width, n_stages) — memoized so
-    eager per-batch ``cnn_apply`` calls don't re-walk the graph."""
-    graph = _cached_dpn(topo, bits)
-    costs = _conv_layer_costs(graph, len(topo.conv_layers))
-    return partition_stages(costs, n_stages)
+def _cached_layout(topo, n_stages: int) -> StageAssignment:
+    """The DP partition of the layers into ``n_stages``, costed by
+    ``graph.conv_layer_flops`` (the actor graph's per-layer FLOPs in
+    closed form, so no graph is built), by units: a residual block's
+    layers stay in one stage, so a skip edge never crosses a stage
+    boundary and the plan stays a chain of stages. Memoized per
+    (topology, n_stages)."""
+    costs = conv_layer_flops(topo)
+    units = layer_units(topo.conv_layers, range(len(costs)))
+    by_unit = partition_stages(
+        [sum(costs[li] for li in u) for u in units], n_stages
+    )
+    bounds = tuple(
+        units[b][0] if b < len(units) else len(costs)
+        for b in by_unit.boundaries
+    )
+    return StageAssignment(
+        n_layers=len(costs), boundaries=bounds,
+        stage_costs=by_unit.stage_costs,
+    )
 
 
 def emit_conv_stage(
@@ -286,6 +336,7 @@ def emit_conv_stage(
     block_c: int = 0,
     block_n: int = 0,
     groups: Optional[Sequence] = None,
+    first_layer: int = 0,
 ) -> Callable:
     """Emit one pipeline-stage body: a chain of fused conv actor chains.
 
@@ -298,7 +349,12 @@ def emit_conv_stage(
     ``stream_conv_pyramid`` call (inter-layer slabs VMEM-resident);
     singleton groups lower through today's single-layer
     ``stream_conv_block`` (with its channel/width blocking knobs).
-    ``groups=None`` means all-singleton — the pre-fusion stage body.
+    ``first_layer`` is the plan's index of the stage's first layer: a
+    group that carries residual joins runs a kernel named after the plan
+    layers it computes (``stream_conv_residual_pallas_l<first>_<last>``).
+    ``groups=None`` means the minimal groups — every layer alone but a
+    residual net's basic blocks, one group each (a join needs its block's
+    input in the same kernel).
 
     ``act_bits`` may be a single width for the whole stage or a per-layer
     tuple (mixed-bitwidth plans); ``int8_scales`` (one
@@ -341,7 +397,9 @@ def emit_conv_stage(
         DEFAULT_BACKEND if backend is None else backend
     )
     if groups is None:
-        group_plan = tuple(((li,), 0) for li in range(len(specs)))
+        group_plan = tuple(
+            (u, 0) for u in layer_units(specs, range(len(specs)))
+        )
     else:
         group_plan = tuple((tuple(g), int(br)) for g, br in groups)
         covered = [li for g, _ in group_plan for li in g]
@@ -379,6 +437,12 @@ def emit_conv_stage(
                     x,
                     [layer_params[li]["w"] for li in g],
                     [layer_params[li]["b"] for li in g],
+                    projections=[
+                        (layer_params[li]["proj"]["w"],
+                         layer_params[li]["proj"]["b"])
+                        if "proj" in layer_params[li] else None
+                        for li in g
+                    ],
                     layers=[specs[li] for li in g],
                     act_bits=tuple(bits[li] for li in g),
                     int8_scales=(
@@ -388,6 +452,10 @@ def emit_conv_stage(
                     ),
                     block_rows=block_rows,
                     backend=resolved,
+                    name=(
+                        f"stream_conv_residual_pallas_l{first_layer + g[0]}"
+                        f"_{first_layer + g[-1]}"
+                    ),
                 )
         return x
 
@@ -399,15 +467,19 @@ def emit_conv_stage(
 
 
 def _bake_conv_params(conv_params, quant: QuantSpec):
-    """Mirror the fake-quant reference composition order: pow2 projection
-    (STE) first, then fixed-point fake-quant of every tensor.
+    """Fold each conv's eval-mode batch norm into its kernel and bias
+    (``models.cnn.fold_bn``; a projection shortcut's too), then mirror the
+    fake-quant reference composition order: pow2 projection (STE) first,
+    then fixed-point fake-quant of every tensor — so the folded kernel is
+    what gets quantized, as deployments do.
 
     Returns ``(baked_params, w_scales)``. Under ``quant.int8_compute`` the
     weights bake to int8 CODES on the same dynamic pow2 grid
     ``fake_quant_dynamic`` would use (``codes * scale ==
-    fake_quant_dynamic(w, bits)`` exactly), and ``w_scales`` carries the
-    static per-layer pow2 scale the kernels fold into their int32
-    dequantization; otherwise ``w_scales`` is None.
+    fake_quant_dynamic(w, bits)`` exactly), and ``w_scales`` carries, per
+    layer, the static pow2 scale the kernels fold into their int32
+    dequantization — a ``(conv, projection)`` pair for a layer with a
+    projection shortcut; otherwise ``w_scales`` is None.
     """
     from repro.core.quant.fixed_point import (
         dynamic_spec,
@@ -415,22 +487,35 @@ def _bake_conv_params(conv_params, quant: QuantSpec):
         quantize_fixed,
     )
     from repro.core.quant.pow2 import project_pow2_ste
+    from repro.models.cnn import fold_bn
 
-    out, w_scales = [], []
-    for i, p in enumerate(conv_params):
-        w, b = p["w"], p["b"]
-        wb = quant.conv_weight_bits(i)
+    def bake(w, b, wb):
         if quant.pow2_weights:
             w = project_pow2_ste(w)
         if quant.int8_compute:
             wspec = dynamic_spec(w, wb)
-            w = quantize_fixed(w, wspec).astype(jnp.int8)
-            b = fake_quant_dynamic(b, wb)
-            w_scales.append(float(wspec.scale))
-        elif wb is not None:
+            return (
+                quantize_fixed(w, wspec).astype(jnp.int8),
+                fake_quant_dynamic(b, wb),
+                float(wspec.scale),
+            )
+        if wb is not None:
             w = fake_quant_dynamic(w, wb)
             b = fake_quant_dynamic(b, wb)
-        out.append({"w": w, "b": b})
+        return w, b, None
+
+    out, w_scales = [], []
+    for i, p in enumerate(conv_params):
+        p = fold_bn(p)
+        wb = quant.conv_weight_bits(i)
+        w, b, scale = bake(p["w"], p["b"], wb)
+        baked = {"w": w, "b": b}
+        if "proj" in p:
+            pw_, pb, p_scale = bake(p["proj"]["w"], p["proj"]["b"], wb)
+            baked["proj"] = {"w": pw_, "b": pb}
+            scale = (scale, p_scale)
+        out.append(baked)
+        w_scales.append(scale)
     return tuple(out), (tuple(w_scales) if quant.int8_compute else None)
 
 
@@ -469,11 +554,14 @@ _pow2_linear_ste.defvjp(_pow2_linear_ste_fwd, _pow2_linear_ste_bwd)
 
 
 def _emit_head(
-    fc_params, quant: QuantSpec, backend: str, head_in_bits=None
+    fc_params, quant: QuantSpec, backend: str, head_in_bits=None,
+    global_pool: bool = False,
 ) -> Callable:
-    """Emit the classifier head: flatten -> FC stack, with the same
+    """Emit the classifier head: flatten (or, with ``global_pool``,
+    average the last feature map over H and W) -> FC stack, with the same
     quantization contract as the conv stages (tanh + feature-stream quant
-    between hidden layers; logits unquantized, as in the reference).
+    between hidden layers; logits unquantized, as in the reference; the
+    pooled average is not requantized).
 
     Under ``int8_compute`` with a packed pow2 head, each FC forwards
     through the integer shift-add rendering: the first FC's input grid is
@@ -514,6 +602,8 @@ def _emit_head(
     )
 
     def head_fn(h):
+        if global_pool:
+            h = jnp.mean(h, axis=(1, 2))
         h = h.reshape(h.shape[0], -1)
         for i, p in enumerate(baked):
             if quant.packed_fc_head:
@@ -554,12 +644,12 @@ class CompiledStage:
 class CompiledDHM:
     """Executable lowering of a CNN topology: quantized parameters +
     per-stage fused-kernel closures + the FC head, plus the IR artifacts
-    (DPN graph, stage assignment) the lowering went through."""
+    (stage assignment; the DPN graph on demand) the lowering went
+    through."""
 
     topo: object
     quant: QuantSpec
     backend: str
-    graph: DataflowGraph
     assignment: StageAssignment
     stages: tuple
     conv_params: tuple  # per conv layer {"w", "b"}, quantization baked
@@ -570,6 +660,12 @@ class CompiledDHM:
     @property
     def n_stages(self) -> int:
         return len(self.stages)
+
+    @functools.cached_property
+    def graph(self) -> DataflowGraph:
+        """The paper-granularity actor graph of the topology (built on
+        first read; compiling the plan does not need it)."""
+        return _cached_dpn(self.topo, self.quant.stream_bits)
 
     def stage_quant_kwargs(self, stage: int) -> dict:
         """The quantization kwargs ``emit_conv_stage`` needs to re-emit
@@ -733,9 +829,10 @@ def compile_dhm(
     validate_topology(topo)
     resolved = validate_backend(DEFAULT_BACKEND if backend is None else backend)
     n_conv = len(topo.conv_layers)
-    if not 1 <= n_stages <= n_conv:
+    n_units = len(layer_units(topo.conv_layers, range(n_conv)))
+    if not 1 <= n_stages <= n_units:
         raise ValueError(
-            f"n_stages must be in [1, {n_conv}] for {topo.name}, got {n_stages}"
+            f"n_stages must be in [1, {n_units}] for {topo.name}, got {n_stages}"
         )
     if quant.per_layer_bits is not None and len(quant.per_layer_bits) != n_conv:
         raise ValueError(
@@ -758,8 +855,7 @@ def compile_dhm(
             f"vmem_budget must be >= 0 (0 disables fusion), got {vmem_budget}"
         )
 
-    graph = _cached_dpn(topo, quant.stream_bits)
-    assignment = _cached_layout(topo, quant.stream_bits, n_stages)
+    assignment = _cached_layout(topo, n_stages)
 
     conv_params, w_scales = _bake_conv_params(params["conv"], quant)
     if quant.int8_compute:
@@ -767,14 +863,21 @@ def compile_dhm(
 
         # Layer i's input stream is layer i-1's quantized output; layer 0
         # quantizes the frame onto its own stream grid (the plan contract
-        # for int8 input ingestion).
-        int8_scales = tuple(
-            Int8Scales(
-                in_bits=quant.conv_act_bits(max(i - 1, 0)),
-                w_scale=w_scales[i],
+        # for int8 input ingestion). A projection reads its block's input
+        # stream, the input of layer i - BLOCK_SPAN + 1.
+        def in_bits(i):
+            return quant.conv_act_bits(max(i - 1, 0))
+
+        def scales(i):
+            ws = w_scales[i]
+            if not isinstance(ws, tuple):
+                return Int8Scales(in_bits=in_bits(i), w_scale=ws)
+            proj = Int8Scales(
+                in_bits=in_bits(i - BLOCK_SPAN + 1), w_scale=ws[1]
             )
-            for i in range(n_conv)
-        )
+            return Int8Scales(in_bits=in_bits(i), w_scale=ws[0], proj=proj)
+
+        int8_scales = tuple(scales(i) for i in range(n_conv))
     else:
         int8_scales = ()
     elem_bytes = 1 if quant.int8_compute else 4
@@ -822,6 +925,7 @@ def compile_dhm(
                     block_c=block_c,
                     block_n=block_n,
                     groups=local_groups,
+                    first_layer=idxs[0],
                 ),
                 cost_flops=assignment.stage_costs[s],
                 groups=groups,
@@ -830,13 +934,13 @@ def compile_dhm(
         )
 
     head_fn = _emit_head(
-        params["fc"], quant, resolved, head_in_bits=per_layer_act[-1]
+        params["fc"], quant, resolved, head_in_bits=per_layer_act[-1],
+        global_pool=getattr(topo, "global_pool", False),
     )
     return CompiledDHM(
         topo=topo,
         quant=quant,
         backend=resolved,
-        graph=graph,
         assignment=assignment,
         stages=tuple(stages),
         conv_params=conv_params,

@@ -638,7 +638,8 @@ class Engine:
         plan = self.plan
         stage_fns = [
             emit_conv_stage(
-                st.specs, backend=backend, **plan.stage_quant_kwargs(st.index)
+                st.specs, backend=backend, first_layer=st.conv_layers[0],
+                **plan.stage_quant_kwargs(st.index),
             )
             for st in plan.stages
         ]

@@ -21,6 +21,13 @@ appears — the group stops growing and the layer falls back to today's
 single-layer stage (which has its own channel/width blocking). Singleton
 groups are therefore always legal: with a zero budget the plan is
 exactly the per-layer plan.
+
+A basic block of a residual net (its ``join`` layer and the layers before
+it, ``halo.BLOCK_SPAN`` in all) is indivisible: the planner grows groups
+by units (:func:`layer_units`), so a group either holds a whole block,
+whose input slab the kernel keeps for the join, or none of it. A block
+that fits no group under the budget is a compile error naming it; there
+is no single-layer fallback for half a block.
 """
 from __future__ import annotations
 
@@ -29,6 +36,7 @@ from typing import Optional, Sequence
 
 from repro.core.dhm.mapping import partition_greedy_budget
 from repro.kernels.stream_conv.halo import (
+    BLOCK_SPAN,
     as_pyramid_layers,
     group_geometry,
     working_set_bytes,
@@ -52,6 +60,33 @@ class FusionGroup:
     @property
     def fused(self) -> bool:
         return len(self.layers) > 1
+
+
+def layer_units(conv_layers: Sequence, layer_indices: Sequence[int]) -> tuple:
+    """The indivisible units of a contiguous run of conv layers, in
+    order: each basic block (a layer with a residual ``join`` and the
+    ``BLOCK_SPAN - 1`` layers before it) as one unit, every other layer
+    alone. A run that starts or ends inside a block raises ``ValueError``
+    naming the block."""
+    idxs = tuple(layer_indices)
+    block = {}
+    for j, spec in enumerate(conv_layers):
+        if getattr(spec, "join", "none") != "none":
+            members = tuple(range(j - BLOCK_SPAN + 1, j + 1))
+            for li in members:
+                block[li] = members
+    units = []
+    for li in idxs:
+        unit = block.get(li, (li,))
+        if units and units[-1] == unit:
+            continue
+        if not set(unit) <= set(idxs):
+            raise ValueError(
+                f"layers {idxs[0]}..{idxs[-1]} cut the basic block of "
+                f"layers {unit[0]}..{unit[-1]}"
+            )
+        units.append(unit)
+    return tuple(units)
 
 
 def plan_elem_bytes(quant) -> int:
@@ -131,12 +166,14 @@ def _block_row_candidates(topo, idxs) -> list:
 
 
 def _fit_block_rows(
-    topo, idxs, budget: int, elem_bytes: int = 4
+    topo, idxs, budget: int, elem_bytes: int = 4, whole_frame: bool = False
 ) -> Optional[tuple]:
     """Largest feasible (block_rows, working_set) for fusing ``idxs``
     under ``budget``: whole-frame first, then halved row blocks down to
-    one row. None if nothing fits (or the geometry is unsupported)."""
-    for r in _block_row_candidates(topo, idxs):
+    one row (only the whole frame with ``whole_frame``). None if nothing
+    fits (or the geometry is unsupported)."""
+    candidates = _block_row_candidates(topo, idxs)
+    for r in candidates[:1] if whole_frame else candidates:
         try:
             ws = group_working_set(
                 topo, idxs, block_rows=r, elem_bytes=elem_bytes
@@ -192,11 +229,13 @@ def plan_fusion_groups(
     """Partition a contiguous run of conv layers into maximal fusion
     groups under the VMEM budget.
 
-    Greedy left-to-right: each group is extended while the grown group
-    still fits (so groups are maximal), and closed when the next layer
-    would blow the budget — that layer starts the next group. Layers that
-    cannot fuse at all become singleton groups, which lower through the
-    single-layer kernel path (bit-identical to the pre-fusion plan).
+    Greedy left-to-right over the run's units (:func:`layer_units`):
+    each group is extended while the grown group still fits (so groups
+    are maximal), and closed when the next unit would blow the budget —
+    that unit starts the next group. Layers that cannot fuse at all
+    become singleton groups, which lower through the single-layer kernel
+    path (bit-identical to the pre-fusion plan); a basic block that fits
+    no group raises ``ValueError`` naming it.
     ``vmem_budget=None`` means :data:`DEFAULT_VMEM_BUDGET`; ``0`` turns
     fusion off entirely. ``elem_bytes`` is the streamed-slab byte width
     the costing charges (``plan_elem_bytes(quant)`` — 1 for true-int8
@@ -212,26 +251,40 @@ def plan_fusion_groups(
     if budget < 0:
         raise ValueError(f"vmem_budget must be >= 0, got {budget}")
 
+    units = layer_units(topo.conv_layers, idxs)
     fit_cache: dict = {}
 
+    def run_of(i: int, j: int) -> tuple:
+        return tuple(li for u in units[i:j] for li in u)
+
     def fit_of(i: int, j: int):
+        # A group of several units holding a residual block keeps whole
+        # frames: row blocks would recompute every layer's halo once per
+        # block (3.2x the MACs for ResNet-18's first ten layers in 28
+        # blocks of 2 rows), more than the HBM round trip fusion saves.
+        whole = j - i > 1 and any(len(u) > 1 for u in units[i:j])
         if (i, j) not in fit_cache:
             fit_cache[(i, j)] = _fit_block_rows(
-                topo, idxs[i:j], budget, elem_bytes
+                topo, run_of(i, j), budget, elem_bytes, whole_frame=whole
             )
         return fit_cache[(i, j)]
 
     def fits(i: int, j: int) -> bool:
-        if j - i == 1:
+        if len(run_of(i, j)) == 1:
             return True  # singletons lower through the single-layer path
         if budget == 0:
             return False
         return fit_of(i, j) is not None
 
     groups = []
-    for i, j in partition_greedy_budget(len(idxs), fits):
-        run = idxs[i:j]
-        fit = fit_of(i, j) if j - i > 1 else None
+    for i, j in partition_greedy_budget(len(units), fits):
+        run = run_of(i, j)
+        fit = fit_of(i, j) if len(run) > 1 else None
+        if len(run) > 1 and fit is None:
+            raise ValueError(
+                f"the basic block of layers {run[0]}..{run[-1]} fits no "
+                f"fusion group under a {budget} B VMEM budget"
+            )
         groups.append(
             FusionGroup(
                 layers=run,

@@ -7,7 +7,13 @@ unidirectional stream edges. The granularity follows the paper exactly:
   multipliers + one adder-tree actor + a (K-1)-line line buffer;
 - one **neuron sum** actor per output map (sums C engine outputs + bias);
 - one **activation** actor per output map;
-- one **pool** actor per output map.
+- one **pool** actor per output map;
+- for a layer that closes a residual block, one **join** (add) actor per
+  output map between its neuron sum and its activation, fed by the
+  block's input stream, or for a projection shortcut by a 1x1 conv
+  engine per (map, block-input channel) and their neuron sum.
+
+Batch norm costs no actor: the compiler folds it into the conv weights.
 
 For the Fig. 2 example (C=3, N=5, K=3) this yields 15 conv engines
 (135 multipliers, 15 adder trees), 5 neuron adders and 5 activations —
@@ -32,6 +38,7 @@ class ActorKind(enum.Enum):
     NEURON_SUM = "neuron_sum"  # sums C conv-engine streams + bias
     ACTIVATION = "activation"
     POOL = "pool"
+    JOIN = "join"  # residual add of a block's shortcut
     DENSE = "dense"
     BLOCK = "block"  # coarse-grain actor (transformer layer etc.)
     SINK = "sink"
@@ -142,13 +149,19 @@ def cnn_to_dpn(topo, *, bits: int) -> DataflowGraph:
     widths. Only the feature extractor is expanded (the paper maps the
     feature extractor; Table 4 footnote).
     """
+    from repro.kernels.stream_conv.halo import BLOCK_SPAN
+
     actors: list = [Actor(name="input", kind=ActorKind.SOURCE, layer=0)]
     edges: list = []
     prev_outputs = ["input"]
+    layer_inputs = []  # the streams each conv layer reads
     layer_idx = 0
     h_in, w_in = topo.input_shape
+    proj = topo.projection_shapes() if hasattr(topo, "projection_shapes") else {}
     for li, (c_in, n_out, k, h_out, w_out) in enumerate(topo.conv_shapes()):
         spec = topo.conv_layers[li]
+        join = getattr(spec, "join", "none")
+        layer_inputs.append(prev_outputs)
         layer_idx += 1
         acc_bits = 2 * bits + _ceil_log2(k * k * max(1, c_in))
         # The sliding-window buffer holds (K-1) *input* lines: with SAME
@@ -205,6 +218,12 @@ def cnn_to_dpn(topo, *, bits: int) -> DataflowGraph:
             )
             for e in engine_outs:
                 edges.append((e, sum_name))
+            if join != "none":
+                sum_name = _join_actors(
+                    actors, edges, li, n, sum_name, join,
+                    layer_inputs[li - BLOCK_SPAN + 1], proj.get(li),
+                    h_out * w_out, bits, acc_bits, layer_idx,
+                )
             act_name = f"act{li + 1}_n{n}"
             actors.append(
                 Actor(
@@ -224,8 +243,9 @@ def cnn_to_dpn(topo, *, bits: int) -> DataflowGraph:
                 # (NOT h_out // window — that silently mis-shapes every
                 # overlapping pool). The streaming pool buffers (pw - 1)
                 # conv-output lines regardless of stride.
-                h_p = (h_out - pw) // ps + 1
-                w_p = (w_out - pw) // ps + 1
+                pp2 = 2 * getattr(spec, "pool_pad", 0)
+                h_p = (h_out + pp2 - pw) // ps + 1
+                w_p = (w_out + pp2 - pw) // ps + 1
                 actors.append(
                     Actor(
                         name=pool_name,
@@ -249,6 +269,74 @@ def cnn_to_dpn(topo, *, bits: int) -> DataflowGraph:
     g = DataflowGraph(name=topo.name, actors=actors, edges=edges)
     g.validate()
     return g
+
+
+def _join_actors(
+    actors, edges, li, n, sum_name, join, block_in, proj, hw, bits,
+    acc_bits, layer_idx,
+) -> str:
+    """Map ``n``'s residual join of conv layer ``li``: an add actor fed by
+    the neuron sum and by the block input's stream ``n`` (identity) or by
+    a 1x1 projection (one engine per block-input channel, then their
+    neuron sum). Returns the add actor's name."""
+    add_name = f"join{li + 1}_n{n}"
+    actors.append(
+        Actor(
+            name=add_name, kind=ActorKind.JOIN, adders=1, flops=1.0 * hw,
+            stream_bytes=hw * acc_bits / 8.0, layer=layer_idx,
+        )
+    )
+    edges.append((sum_name, add_name))
+    if join == "identity":
+        edges.append((block_in[n % len(block_in)], add_name))
+        return add_name
+    c_blk = proj[0]
+    psum = f"projsum{li + 1}_n{n}"
+    for c in range(c_blk):
+        name = f"proj{li + 1}_n{n}_c{c}"
+        actors.append(
+            Actor(
+                name=name, kind=ActorKind.CONV_ENGINE, multipliers=1,
+                adders=1, flops=2.0 * hw, param_bytes=bits / 8.0,
+                stream_bytes=hw * acc_bits / 8.0, layer=layer_idx,
+            )
+        )
+        edges.append((block_in[c % len(block_in)], name))
+        edges.append((name, psum))
+    actors.append(
+        Actor(
+            name=psum, kind=ActorKind.NEURON_SUM, adders=1,
+            flops=2.0 * c_blk * hw, stream_bytes=hw * acc_bits / 8.0,
+            layer=layer_idx,
+        )
+    )
+    edges.append((psum, add_name))
+    return add_name
+
+
+def conv_layer_flops(topo) -> list:
+    """Per conv layer, the FLOPs :func:`cnn_to_dpn`'s actors of that layer
+    carry, in closed form from the shapes: conv engines ``2*K*K*H*W``
+    each of N*C, neuron sums ``2*C*H*W`` each of N, activations ``H*W``,
+    pools ``P*P*H'*W'``, and for a join one add ``H*W`` per map plus a
+    projection's 1x1 engines and sums. The compiler's stage costs, with
+    no actor graph built."""
+    proj = topo.projection_shapes() if hasattr(topo, "projection_shapes") else {}
+    out = []
+    for li, (c, n, k, h, w) in enumerate(topo.conv_shapes()):
+        spec = topo.conv_layers[li]
+        hw = h * w
+        f = n * c * 2 * k * k * hw + n * 2 * c * hw + n * hw
+        pw, ps = spec.pool_cfg
+        if pw:
+            pp2 = 2 * getattr(spec, "pool_pad", 0)
+            f += n * pw * pw * ((h + pp2 - pw) // ps + 1) * ((w + pp2 - pw) // ps + 1)
+        if getattr(spec, "join", "none") != "none":
+            f += n * hw
+        if li in proj:
+            f += n * proj[li][0] * 2 * hw + n * 2 * proj[li][0] * hw
+        out.append(float(f))
+    return out
 
 
 def layer_costs_to_dpn(

@@ -65,12 +65,20 @@ from repro.kernels.stream_conv.epilogue import (
     strided_view,
     validate_epilogue,
 )
-from repro.kernels.stream_conv.halo import group_geometry
+from repro.kernels.stream_conv.halo import BLOCK_SPAN, group_geometry
+
+# Scoped VMEM the residual and single-layer kernels may use. A v5e
+# TensorCore has 128 MiB of VMEM; Mosaic's default scope is 16 MiB, which
+# the fusion planner's budget (a sum of slabs, not of Mosaic's tiled
+# buffers) is set against. Tiled, a 56x56x64 block or a row block of a
+# 224-px 3-channel frame (3 of 128 lanes used) runs past that default.
+# The chain pyramid keeps the default scope, and so its compiled form.
+WIDE_VMEM = pltpu.CompilerParams(vmem_limit_bytes=96 * 1024 * 1024)
 
 
 def _kernel_body(
     x_blk, w_ref, b_ref, o_ref, acc_ref, *, k, s, r_conv, w_conv, act,
-    pool, pool_stride, act_bits, int8_scales, out_dtype,
+    pool, pool_stride, act_bits, int8_scales, out_dtype, pool_pad=None,
 ):
     """Shared body: x_blk is the assembled ((r_conv-1)*s + k,
     (w_conv-1)*s + k, bc) input tile (body + halo strips).
@@ -82,6 +90,14 @@ def _kernel_body(
     arithmetic, same epilogue contract."""
     cb = pl.program_id(4)
     n_cb = pl.num_programs(4)
+    origin = None
+    if pool_pad is not None:
+        # The block's first row and column in the conv map: the frame was
+        # shifted by pp conv rows and columns, which pool as -inf.
+        pp, rows, cols, r, wc = pool_pad
+        origin = (
+            pl.program_id(1) * r - pp, pl.program_id(2) * wc - pp, rows, cols,
+        )
 
     @pl.when(cb == 0)
     def _zero():
@@ -112,7 +128,7 @@ def _kernel_body(
             y = y.astype(jnp.float32) * int8_scales.deq_scale
         y = apply_epilogue(
             y, b_ref[...], act=act, pool=pool,
-            pool_stride=pool_stride, act_bits=act_bits,
+            pool_stride=pool_stride, act_bits=act_bits, pool_pad=origin,
         )
         o_ref[0] = y.astype(out_dtype)
 
@@ -149,7 +165,7 @@ def _block_multiple(k: int, s: int, pw: int, ps: int) -> tuple:
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "k", "stride", "act", "pool", "pool_stride", "act_bits",
+        "k", "stride", "act", "pool", "pool_stride", "pool_pad", "act_bits",
         "int8_scales", "block_r", "block_w", "block_c", "block_n",
         "out_dtype", "interpret",
     ),
@@ -164,6 +180,7 @@ def stream_conv_fused_pallas(
     act: str = "none",
     pool: int = 0,
     pool_stride: int | None = None,
+    pool_pad: int = 0,
     act_bits: int | None = None,
     int8_scales=None,
     block_r: int = 8,
@@ -175,9 +192,15 @@ def stream_conv_fused_pallas(
 ) -> jax.Array:
     """Fused streaming conv. VALID, conv stride ``stride``; ``pool`` is a
     square max-pool window (0 = none) sliding with ``pool_stride``
-    (default: the window); act in {none, relu, tanh}; ``act_bits``
-    quantizes the output feature stream in-kernel. Returns (B, H', W', N)
-    where H', W' are the pooled output dims.
+    (default: the window), padded by ``pool_pad`` -inf rows and columns
+    per side; act in {none, relu, tanh}; ``act_bits`` quantizes the output
+    feature stream in-kernel. Returns (B, H', W', N) where H', W' are the
+    pooled output dims.
+
+    A padded pool computes ``pool_pad`` extra conv rows and columns on
+    each side of the map (the frame is shifted by ``pool_pad * stride``
+    zero pixels) and masks them to -inf before pooling, so every block
+    pools exactly as the padded pool.
 
     ``int8_scales`` (``epilogue.Int8Scales``) selects the true-int8
     rendering: ``x`` must arrive pre-quantized as int8 stream codes (the
@@ -209,6 +232,17 @@ def stream_conv_fused_pallas(
         raise ValueError(
             f"conv output {h_out}x{w_out} too small for {pw}x{pw} pool"
         )
+    pad_origin = None
+    if pool_pad:
+        if not pw or 2 * pool_pad > pw:
+            raise ValueError(
+                f"pool_pad={pool_pad} needs a pool window of at least "
+                f"{2 * pool_pad}, got {pw}"
+            )
+        shift = pool_pad * s
+        x = jnp.pad(x, ((0, 0), (shift, 0), (shift, 0), (0, 0)))
+        pad_origin = (pool_pad, h_out, w_out)
+        h_out, w_out = h_out + 2 * pool_pad, w_out + 2 * pool_pad
 
     mult, hb, overlap = _block_multiple(k, s, pw, ps)
     r = round_up(max(block_r, mult), mult)
@@ -250,6 +284,8 @@ def stream_conv_fused_pallas(
         pool_stride=pool_stride, act_bits=act_bits,
         int8_scales=int8_scales, out_dtype=out_dtype,
     )
+    if pad_origin is not None:
+        kw["pool_pad"] = (*pad_origin, r, wc)
 
     # With one column block the body tile spans the whole padded width, so
     # only a bottom halo strip remains and every tile's trailing dims equal
@@ -312,6 +348,7 @@ def stream_conv_fused_pallas(
                 jnp.int32 if int8_scales is not None else jnp.float32,
             )
         ],
+        compiler_params=WIDE_VMEM,
         interpret=interpret,
     )(*inputs)
     return out[:, :h_keep, :w_keep, :n]
@@ -344,22 +381,55 @@ def _merge_rows(x):
     return x.reshape(r * w, lanes)
 
 
+def _shortcut(src, col0: int, g, src_scale, proj_scales, proj_refs):
+    """A residual join's shortcut for one block: every ``res_stride``-th
+    row and column of the held block-input slab ``src`` (from row
+    ``res_off``, column ``col0``), as fp32 values, or their 1x1
+    projection (one more MXU dot, its own bias and int8 contract) for a
+    ``project`` join. ``src_scale`` is the block input's stream step
+    (None on the float path)."""
+    st = g.res_stride
+    v = strided_view(src, 0, g.res_off, g.conv_slab_rows, st)
+    v = strided_view(v, 1, col0, g.conv_cols, st)
+    if g.join == "identity":
+        return v if src_scale is None else v.astype(jnp.float32) * src_scale
+    w_ref, b_ref = proj_refs
+    operand = _merge_rows(v)
+    if proj_scales is not None:
+        y = jnp.dot(operand, w_ref[...], preferred_element_type=jnp.int32)
+        y = y.astype(jnp.float32) * proj_scales.deq_scale
+    else:
+        y = jnp.dot(
+            operand.astype(jnp.float32), w_ref[...].astype(jnp.float32),
+            preferred_element_type=jnp.float32,
+        )
+    return y.reshape(g.conv_slab_rows, g.conv_cols, g.n_out) + b_ref[...]
+
+
 def _pyramid_kernel(*refs, geom, act_bits, int8_scales, out_dtype):
     """Kernel body: stream one row block of the final output through the
     whole fusion group. refs = (x_ref, w_ref0, b_ref0, w_ref1, b_ref1, ...,
-    o_ref). Every inter-layer slab lives in VMEM for the block's lifetime;
-    nothing is written back until the last layer's pooled rows.
+    then (w, b) of each ``project`` join in layer order, o_ref). Every
+    inter-layer slab lives in VMEM for the block's lifetime; nothing is
+    written back until the last layer's pooled rows.
 
     ``act_bits`` is a per-layer tuple; ``int8_scales`` (None or a
     per-layer tuple of ``Int8Scales``) selects true integer arithmetic:
     the resident frame and every inter-layer slab are int8 stream CODES
     (1 byte/element in VMEM — intermediate epilogues emit ``codes_out``),
     each layer's single matmul contracts integers into int32, and only
-    the group's final epilogue dequantizes to fp32 grid values."""
+    the group's final epilogue dequantizes to fp32 grid values.
+
+    A layer with a residual join adds its shortcut before its epilogue's
+    activation. The shortcut reads the block's input slab, held in VMEM
+    from the block's first layer (before its column padding), so the
+    skip edge never leaves the chip."""
     x_ref, o_ref = refs[0], refs[-1]
-    wb = refs[1:-1]
-    rb = pl.program_id(1)
     n_layers = len(geom.layers)
+    wb = refs[1 : 1 + 2 * n_layers]
+    proj = iter(zip(refs[1 + 2 * n_layers : -1 : 2],
+                    refs[2 + 2 * n_layers : -1 : 2]))
+    rb = pl.program_id(1)
 
     g0 = geom.layers[0]
     start0 = g0.in_mult * rb + g0.in_off + geom.input_row_shift
@@ -367,6 +437,7 @@ def _pyramid_kernel(*refs, geom, act_bits, int8_scales, out_dtype):
     if int8_scales is None:
         slab = slab.astype(jnp.float32)
 
+    held = None  # (block-input slab, its first column, its stream step)
     for i, g in enumerate(geom.layers):
         sc = None if int8_scales is None else int8_scales[i]
         if i > 0:
@@ -383,6 +454,14 @@ def _pyramid_kernel(*refs, geom, act_bits, int8_scales, out_dtype):
             slab = jnp.where(
                 (rows >= 0) & (rows < g.in_rows), slab, jnp.zeros_like(slab)
             )
+        closer = i + BLOCK_SPAN - 1
+        if closer < n_layers and geom.layers[closer].join != "none":
+            held = (
+                slab,
+                geom.in_pad_cols[0] if i == 0 else 0,
+                None if sc is None else sc.in_scale,
+            )
+        if i > 0:
             lc, rc = g.pads[1]
             if lc or rc:
                 slab = jnp.pad(slab, ((0, 0), (lc, rc), (0, 0)))
@@ -404,12 +483,94 @@ def _pyramid_kernel(*refs, geom, act_bits, int8_scales, out_dtype):
                 w_flat.astype(jnp.float32),
                 preferred_element_type=jnp.float32,
             ).reshape(g.conv_slab_rows, g.conv_cols, g.n_out)
+        residual = None
+        if g.join != "none":
+            src, col0, src_scale = held
+            residual = _shortcut(
+                src, col0, g, src_scale, None if sc is None else sc.proj,
+                next(proj) if g.join == "project" else None,
+            )
         slab = apply_epilogue(
             y, wb[2 * i + 1][...], act=g.act, pool=g.pw,
             pool_stride=g.ps, act_bits=act_bits[i], pool_first=True,
             codes_out=sc is not None and i < n_layers - 1,
+            residual=residual,
         )
     o_ref[0] = slab.astype(out_dtype)
+
+
+def _pyramid_call(
+    x, weights, biases, projections, *, layers, act_bits, int8_scales,
+    block_rows, out_dtype, interpret, compiler_params=None,
+):
+    """The pallas_call of a fusion group (see
+    :func:`stream_conv_pyramid_pallas`); ``projections`` holds the (w, b)
+    of each ``project`` join, in layer order."""
+    b, h, w, c = x.shape
+    if int8_scales is not None and x.dtype != jnp.int8:
+        raise ValueError(
+            f"int8_scales requires a pre-quantized int8 frame, got {x.dtype}"
+        )
+    bits = (
+        act_bits
+        if isinstance(act_bits, tuple)
+        else (act_bits,) * len(layers)
+    )
+    kernels = tuple(wt.shape[0] for wt in weights)
+    n_outs = tuple(wt.shape[3] for wt in weights)
+    geom = group_geometry(
+        h, w, c, layers, kernels, n_outs, block_rows=block_rows
+    )
+    lc, rc = geom.in_pad_cols
+    xp = jnp.pad(
+        x,
+        (
+            (0, 0),
+            (geom.in_pad_top, geom.in_pad_rows_total - h - geom.in_pad_top),
+            (lc, rc),
+            (0, 0),
+        ),
+    )
+    rows_tot, cols_tot = xp.shape[1], xp.shape[2]
+
+    grid = (b, geom.n_row_blocks)
+    in_specs = [
+        pl.BlockSpec((1, rows_tot, cols_tot, c), lambda bb, rb: (bb, 0, 0, 0))
+    ]
+    inputs = [xp]
+    for g, wt, bs in zip(geom.layers, weights, biases):
+        # Taps flattened on the host: the (k*k*C, N) matmul operand is
+        # what the kernel reads, with no in-kernel reshape of packed int8.
+        kkc = g.k * g.k * g.in_ch
+        in_specs.append(pl.BlockSpec((kkc, g.n_out), lambda bb, rb: (0, 0)))
+        in_specs.append(pl.BlockSpec((g.n_out,), lambda bb, rb: (0,)))
+        inputs += [wt.reshape(kkc, g.n_out), bs]
+    for wt, bs in projections:
+        ci, n = wt.shape[2], wt.shape[3]
+        in_specs.append(pl.BlockSpec((ci, n), lambda bb, rb: (0, 0)))
+        in_specs.append(pl.BlockSpec((n,), lambda bb, rb: (0,)))
+        inputs += [wt.reshape(ci, n), bs]
+
+    n_last = n_outs[-1]
+    out = pl.pallas_call(
+        functools.partial(
+            _pyramid_kernel, geom=geom, act_bits=bits,
+            int8_scales=int8_scales, out_dtype=out_dtype,
+        ),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec(
+            (1, geom.block_rows, geom.out_cols, n_last),
+            lambda bb, rb: (bb, rb, 0, 0),
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (b, geom.n_row_blocks * geom.block_rows, geom.out_cols, n_last),
+            out_dtype,
+        ),
+        compiler_params=compiler_params,
+        interpret=interpret,
+    )(*inputs)
+    return out[:, : geom.out_rows]
 
 
 @functools.partial(
@@ -442,63 +603,51 @@ def stream_conv_pyramid_pallas(
     is exactly the zero padding those rows carry. Returns the group output
     (B, H', W', N_last).
     """
-    b, h, w, c = x.shape
-    if int8_scales is not None and x.dtype != jnp.int8:
-        raise ValueError(
-            f"int8_scales requires a pre-quantized int8 frame, got {x.dtype}"
-        )
-    bits = (
-        act_bits
-        if isinstance(act_bits, tuple)
-        else (act_bits,) * len(layers)
-    )
-    kernels = tuple(wt.shape[0] for wt in weights)
-    n_outs = tuple(wt.shape[3] for wt in weights)
-    geom = group_geometry(
-        h, w, c, layers, kernels, n_outs, block_rows=block_rows
-    )
-    g0 = geom.layers[0]
-    lc, rc = geom.in_pad_cols
-    xp = jnp.pad(
-        x,
-        (
-            (0, 0),
-            (geom.in_pad_top, geom.in_pad_rows_total - h - geom.in_pad_top),
-            (lc, rc),
-            (0, 0),
-        ),
-    )
-    rows_tot, cols_tot = xp.shape[1], xp.shape[2]
-
-    grid = (b, geom.n_row_blocks)
-    in_specs = [
-        pl.BlockSpec((1, rows_tot, cols_tot, c), lambda bb, rb: (bb, 0, 0, 0))
-    ]
-    inputs = [xp]
-    for g, wt, bs in zip(geom.layers, weights, biases):
-        # Taps flattened on the host: the (k*k*C, N) matmul operand is
-        # what the kernel reads, with no in-kernel reshape of packed int8.
-        kkc = g.k * g.k * g.in_ch
-        in_specs.append(pl.BlockSpec((kkc, g.n_out), lambda bb, rb: (0, 0)))
-        in_specs.append(pl.BlockSpec((g.n_out,), lambda bb, rb: (0,)))
-        inputs += [wt.reshape(kkc, g.n_out), bs]
-
-    n_last = n_outs[-1]
-    out = pl.pallas_call(
-        functools.partial(
-            _pyramid_kernel, geom=geom, act_bits=bits,
-            int8_scales=int8_scales, out_dtype=out_dtype,
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, geom.block_rows, geom.out_cols, n_last),
-            lambda bb, rb: (bb, rb, 0, 0),
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (b, geom.n_row_blocks * geom.block_rows, geom.out_cols, n_last),
-            out_dtype,
-        ),
+    return _pyramid_call(
+        x, weights, biases, (), layers=layers, act_bits=act_bits,
+        int8_scales=int8_scales, block_rows=block_rows, out_dtype=out_dtype,
         interpret=interpret,
-    )(*inputs)
-    return out[:, : geom.out_rows]
+    )
+
+
+
+
+RESIDUAL_KERNEL = "stream_conv_residual_pallas"
+
+
+@functools.lru_cache(maxsize=None)
+def residual_kernel(name: str = RESIDUAL_KERNEL):
+    """The pyramid kernel of a fusion group that carries residual joins,
+    jitted under ``name``, which the device trace shows as the kernel's
+    event name (``%<name>.<n> = ...``); the compiler names each group's
+    kernel ``stream_conv_residual_pallas_l<first>_<last>`` after the conv
+    layers it computes, so a trace reader can price every call.
+
+    Same kernel body as :func:`stream_conv_pyramid_pallas`: each basic
+    block's input slab stays in VMEM from its first layer to the join,
+    where the shortcut (or its 1x1 projection) is added before the
+    activation. Called as ``(x, weights, biases, projections, *, layers,
+    act_bits, int8_scales, block_rows, out_dtype, interpret)``, with
+    ``projections`` the (w (1, 1, C, N), b (N,)) of each ``project``
+    join in layer order."""
+
+    def kernel(
+        x, weights, biases, projections, *, layers, act_bits=None,
+        int8_scales=None, block_rows=0, out_dtype=jnp.float32,
+        interpret=False,
+    ):
+        return _pyramid_call(
+            x, weights, biases, projections, layers=layers,
+            act_bits=act_bits, int8_scales=int8_scales,
+            block_rows=block_rows, out_dtype=out_dtype, interpret=interpret,
+            compiler_params=WIDE_VMEM,
+        )
+
+    kernel.__name__ = kernel.__qualname__ = name
+    return jax.jit(
+        kernel,
+        static_argnames=(
+            "layers", "act_bits", "int8_scales", "block_rows", "out_dtype",
+            "interpret",
+        ),
+    )

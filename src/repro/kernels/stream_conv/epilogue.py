@@ -1,5 +1,5 @@
-"""Shared fused epilogue: bias -> activation -> NxN/stride-s max-pool ->
-feature-stream fixed-point quantization.
+"""Shared fused epilogue: bias (+ residual join) -> activation ->
+NxN/stride-s max-pool -> feature-stream fixed-point quantization.
 
 One definition used by BOTH compiled conv paths (the Pallas kernel body in
 ``conv.py`` and the XLA fallback in ``xla.py``), so the backends cannot
@@ -56,6 +56,9 @@ class Int8Scales:
 
     in_bits: int
     w_scale: float
+    # A ``project`` join's own contract: its input is the block input's
+    # stream, its weights the projection's int8 codes.
+    proj: "Int8Scales | None" = None
 
     @property
     def in_spec(self) -> FixedPointSpec:
@@ -180,15 +183,32 @@ def quantize_stream(x, act_bits: int):
     return q.astype(jnp.int8)
 
 
+def pool_pad_mask(y, row0, col0, rows: int, cols: int):
+    """``y`` (..., R, W, N) with every position outside the conv map
+    ``[0, rows) x [0, cols)`` set to -inf: the padding a padded max-pool
+    reads. ``row0``/``col0`` are the map coordinates of ``y[..., 0, 0, :]``
+    (traced scalars inside a kernel)."""
+    r = jax.lax.broadcasted_iota(jnp.int32, y.shape, y.ndim - 3) + row0
+    c = jax.lax.broadcasted_iota(jnp.int32, y.shape, y.ndim - 2) + col0
+    inside = (r >= 0) & (r < rows) & (c >= 0) & (c < cols)
+    return jnp.where(inside, y, -jnp.inf)
+
+
 def apply_epilogue(
     y, bias, *, act: str, pool: int, pool_stride: int | None = None,
     act_bits: int | None = None, ste: bool = False, pool_first: bool = False,
-    codes_out: bool = False,
+    codes_out: bool = False, residual=None, pool_pad=None,
 ):
     """y: (..., H, W, N) f32; bias: (N,). Returns the block after
-    bias + activation + optional pool x pool / pool_stride max-pool (VALID
-    floor semantics) + optional feature-stream quantization — all
-    in-register/VMEM.
+    bias (+ ``residual``, a residual join's shortcut, added before
+    anything else) + activation + optional pool x pool / pool_stride
+    max-pool (VALID floor semantics) + optional feature-stream
+    quantization — all in-register/VMEM.
+
+    ``pool_pad`` ``(row0, col0, rows, cols)`` marks the block's place in
+    the conv map (see :func:`pool_pad_mask`): positions outside it are
+    -inf when pooled, so a block computed over a padded frame pools
+    exactly as a -inf-padded max-pool.
 
     ``ste=True`` routes the quantization through ``fake_quant_ste``
     (identity gradient inside the representable range) — same forward
@@ -219,14 +239,22 @@ def apply_epilogue(
         )
     pw, ps = normalize_pool(pool, pool_stride)
     y = y + bias.astype(jnp.float32)
+    if residual is not None:
+        y = y + residual
+
+    def pool_y(v):
+        if pool_pad is not None:
+            v = pool_pad_mask(v, *pool_pad)
+        return _maxpool_window(v, pw, ps)
+
     if pool_first and pw:
-        y = _maxpool_window(y, pw, ps)
+        y = pool_y(y)
     if act == "relu":
         y = jnp.maximum(y, 0.0)
     elif act == "tanh":
         y = jnp.tanh(y)
     if not pool_first and pw:
-        y = _maxpool_window(y, pw, ps)
+        y = pool_y(y)
     if act_bits is not None:
         spec = stream_quant_spec(act_bits)
         if ste:

@@ -28,6 +28,13 @@ planner's VMEM cost model):
   frame + per-layer slabs + tap operands + weights) that the fusion
   planner compares against its budget.
 
+A layer with a residual ``join`` closes a basic block of
+:data:`BLOCK_SPAN` layers: its shortcut reads the block's input, the
+input slab of the block's first layer, which the kernel keeps resident.
+Because every interval is affine with the same multiplier chain, the
+shortcut's rows sit at a static offset ``res_off`` inside that slab for
+every block ``rb`` (every ``res_stride``-th row from there).
+
 Row coordinates are *unpadded* feature-map coordinates for every layer:
 SAME row padding is part of the interval composition (offsets go
 negative), and the kernels realize it by masking slab rows outside
@@ -40,6 +47,9 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Sequence
+
+BLOCK_SPAN = 2  # conv layers in a basic block; its last one carries the join
+JOINS = ("none", "identity", "project")
 
 
 def same_pads(d: int, stride: int, k: int) -> tuple:
@@ -61,11 +71,17 @@ class PyramidLayer:
     act: str = "none"
     pool: int = 0
     pool_stride: int | None = None
+    join: str = "none"  # residual join closing a basic block (JOINS)
 
 
 def as_pyramid_layers(specs: Sequence) -> tuple:
     """Normalize duck-typed conv-layer specs (e.g. ``ConvLayerSpec``) into
-    hashable :class:`PyramidLayer` statics."""
+    hashable :class:`PyramidLayer` statics. A padded max-pool has no
+    pyramid rendering (it lowers through the row-blocked single-layer
+    kernel), so it raises ``ValueError``, which the fusion planner reads
+    as "cannot fuse"."""
+    if any(getattr(s, "pool_pad", 0) for s in specs):
+        raise ValueError("the pyramid does not lower a padded max-pool")
     return tuple(
         PyramidLayer(
             padding=s.padding,
@@ -73,6 +89,7 @@ def as_pyramid_layers(specs: Sequence) -> tuple:
             act=s.act,
             pool=s.pool,
             pool_stride=getattr(s, "pool_stride", None),
+            join=getattr(s, "join", "none"),
         )
         for s in specs
     )
@@ -104,6 +121,12 @@ class LayerGeom:
     in_slab_rows: int
     conv_slab_rows: int
     out_slab_rows: int
+    # Residual join: the shortcut's rows start ``res_off`` rows into the
+    # block-start layer's input slab and step by ``res_stride`` (the
+    # block's stride; columns from 0 with the same step).
+    join: str = "none"
+    res_off: int = 0
+    res_stride: int = 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -194,6 +217,7 @@ def group_geometry(
     # Backward pass: affine input interval per layer, last to first.
     mult, off, length = r, 0, r
     geoms = [None] * len(layers)
+    conv_iv = [None] * len(layers)  # (mult, off) of each conv-row interval
     for i in reversed(range(len(layers))):
         layer, k = layers[i], kernels[i]
         h, w, c, pads, conv_r, conv_c, out_r, out_c = dims[i]
@@ -202,6 +226,7 @@ def group_geometry(
         if pw:
             mult, off, length = mult * ps, off * ps, (length - 1) * ps + pw
         conv_slab = length
+        conv_iv[i] = (mult, off)
         s = layer.stride
         tp = pads[0][0]
         mult, off, length = mult * s, off * s - tp, (length - 1) * s + k
@@ -213,6 +238,9 @@ def group_geometry(
             in_mult=mult, in_off=off, in_slab_rows=length,
             conv_slab_rows=conv_slab, out_slab_rows=out_slab,
         )
+    for j, layer in enumerate(layers):
+        if layer.join != "none":
+            geoms[j] = _join_geom(geoms, conv_iv, layers, j)
 
     g0 = geoms[0]
     tp0 = g0.pads[0][0]
@@ -230,6 +258,49 @@ def group_geometry(
         in_pad_top=pad_top,
         in_pad_rows_total=rows_total,
         in_pad_cols=g0.pads[1],
+    )
+
+
+def _join_geom(geoms, conv_iv, layers, j: int) -> LayerGeom:
+    """Layer ``j``'s geometry with its residual join placed: the block's
+    first layer ``j - BLOCK_SPAN + 1`` must be in the group, no layer of
+    the block may pool, and the shortcut (every ``S``-th row and column
+    of the block input, ``S`` the block's stride) must land on the join's
+    conv rows at an offset into the block-start slab that no block
+    index moves."""
+    if layers[j].join not in JOINS:
+        raise ValueError(f"unknown join {layers[j].join!r}; expected {JOINS}")
+    first = j - BLOCK_SPAN + 1
+    if first < 0:
+        raise ValueError(
+            f"layer {j}'s join reads the input of a layer outside the group"
+        )
+    block = range(first, j + 1)
+    if any(geoms[i].pw for i in block):
+        raise ValueError(f"a basic block (layers {first}..{j}) cannot pool")
+    stride = 1
+    for i in block:
+        stride *= geoms[i].stride
+    src, g = geoms[first], geoms[j]
+    cm, co = conv_iv[j]
+    if cm * stride != src.in_mult:
+        raise ValueError(f"layer {j}'s shortcut rows do not track its block")
+    res_off = co * stride - src.in_off
+    if (
+        res_off < 0
+        or res_off + (g.conv_slab_rows - 1) * stride >= src.in_slab_rows
+        or (g.conv_cols - 1) * stride >= src.in_cols
+    ):
+        raise ValueError(f"layer {j}'s shortcut falls outside its block input")
+    if layers[j].join == "identity" and (
+        stride != 1 or src.in_ch != g.n_out
+    ):
+        raise ValueError(
+            f"layer {j}: an identity join needs the block's input shape, "
+            f"got stride {stride} and {src.in_ch} -> {g.n_out} channels"
+        )
+    return dataclasses.replace(
+        g, join=layers[j].join, res_off=res_off, res_stride=stride
     )
 
 
@@ -297,4 +368,15 @@ def working_set_breakdown(
         parts[f"L{i}/weights"] = (
             g.k * g.k * g.in_ch * g.n_out * elem_bytes + g.n_out * 4
         )
+        if g.join != "none":
+            # The shortcut, added to the accumulator before the epilogue.
+            parts[f"L{i}/residual"] = (
+                g.conv_slab_rows * g.conv_cols * g.n_out * acc
+            )
+        if g.join == "project":
+            c_blk = geom.layers[i - BLOCK_SPAN + 1].in_ch
+            parts[f"L{i}/proj_in"] = (
+                g.conv_slab_rows * g.conv_cols * c_blk * elem_bytes
+            )
+            parts[f"L{i}/proj_weights"] = c_blk * g.n_out * elem_bytes + g.n_out * 4
     return parts

@@ -31,6 +31,8 @@ from repro.kernels.backends import (
     validate_backend,
 )
 from repro.kernels.stream_conv.conv import (
+    RESIDUAL_KERNEL,
+    residual_kernel,
     stream_conv_fused_pallas,
     stream_conv_pyramid_pallas,
 )
@@ -62,6 +64,33 @@ def _pad_same(x: jax.Array, k: int, stride: int = 1) -> jax.Array:
     return jnp.pad(x, ((0, 0), ph, pw, (0, 0)))
 
 
+LANES = 128  # lane width of a TPU vector register
+
+
+def _space_to_depth(x: jax.Array, w: jax.Array, stride: int) -> tuple:
+    """A VALID stride-``s`` conv as the equal stride-1 conv on the frame
+    folded s x s into channels: ``(B, H, W, C) -> (B, H/s, W/s, s*s*C)``,
+    the kernel zero-padded to ``K' * s`` taps (``K' = ceil(K / s)``) and
+    folded the same way to ``(K', K', s*s*C, N)``. Exact (the same
+    products, regrouped). A narrow frame then fills s*s times more lanes,
+    and the kernel assembles ``K'^2`` unit-stride taps instead of ``K^2``
+    strided ones: ResNet's 7x7/2 stem on 3 channels becomes a 4x4 conv
+    on 12."""
+    b, h, wd, c = x.shape
+    k, n, s = w.shape[0], w.shape[3], stride
+    kq = -(-k // s)
+    h_out, w_out = (h - k) // s + 1, (wd - k) // s + 1
+    rows, cols = (h_out - 1 + kq) * s, (w_out - 1 + kq) * s
+    x = jnp.pad(x[:, :rows, :cols], (
+        (0, 0), (0, max(0, rows - h)), (0, max(0, cols - wd)), (0, 0)
+    ))
+    x = x.reshape(b, rows // s, s, cols // s, s, c)
+    x = x.transpose(0, 1, 3, 2, 4, 5).reshape(b, rows // s, cols // s, s * s * c)
+    w = jnp.pad(w, ((0, kq * s - k), (0, kq * s - k), (0, 0), (0, 0)))
+    w = w.reshape(kq, s, kq, s, c, n).transpose(0, 2, 1, 3, 4, 5)
+    return x, w.reshape(kq, kq, s * s * c, n)
+
+
 def _validate_int8(int8_scales, act_bits, w) -> None:
     if int8_scales is None:
         return
@@ -77,6 +106,7 @@ def _validate_int8(int8_scales, act_bits, w) -> None:
 def _fused_dispatch(
     x, w, b, *, padding, stride, act, pool, pool_stride, act_bits,
     int8_scales, out_dtype, backend, block_r, block_w, block_c, block_n,
+    pool_pad=0,
 ):
     k = w.shape[0]
     if w.shape[1] != k:
@@ -86,7 +116,7 @@ def _fused_dispatch(
     if backend == "ref":
         return stream_conv_block_ref(
             x, w, b, padding=padding, stride=stride, act=act, pool=pool,
-            pool_stride=pool_stride, act_bits=act_bits,
+            pool_stride=pool_stride, pool_pad=pool_pad, act_bits=act_bits,
             int8_scales=int8_scales,
         ).astype(out_dtype)
     if padding == "SAME":
@@ -100,6 +130,9 @@ def _fused_dispatch(
         from repro.core.quant.fixed_point import quantize_fixed
 
         x = quantize_fixed(x, int8_scales.in_spec).astype(jnp.int8)
+    if stride > 1 and x.shape[-1] * stride * stride <= LANES:
+        x, w = _space_to_depth(x, w, stride)
+        k, stride = w.shape[0], 1
     w_taps = w.reshape(k * k, w.shape[2], w.shape[3])
     if backend == "pallas" and not compiled_pallas_available():
         # Compiled fallback: identical algorithm, lowered through XLA.
@@ -107,7 +140,7 @@ def _fused_dispatch(
         # the block_* tuning knobs are Pallas-only.
         return stream_conv_fused_xla(
             x, w_taps, b, k=k, stride=stride, act=act, pool=pool,
-            pool_stride=pool_stride, act_bits=act_bits,
+            pool_stride=pool_stride, pool_pad=pool_pad, act_bits=act_bits,
             int8_scales=int8_scales, out_dtype=out_dtype,
         )
     return stream_conv_fused_pallas(
@@ -119,6 +152,7 @@ def _fused_dispatch(
         act=act,
         pool=pool,
         pool_stride=pool_stride,
+        pool_pad=pool_pad,
         act_bits=act_bits,
         int8_scales=int8_scales,
         block_r=block_r,
@@ -164,9 +198,9 @@ def stream_conv2d(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "padding", "stride", "act", "pool", "pool_stride", "act_bits",
-        "int8_scales", "backend", "out_dtype", "block_r", "block_w",
-        "block_c", "block_n",
+        "padding", "stride", "act", "pool", "pool_stride", "pool_pad",
+        "act_bits", "int8_scales", "backend", "out_dtype", "block_r",
+        "block_w", "block_c", "block_n",
     ),
 )
 def stream_conv_block(
@@ -179,6 +213,7 @@ def stream_conv_block(
     act: str = "relu",
     pool: int = 2,
     pool_stride: int | None = None,
+    pool_pad: int = 0,
     act_bits: int | None = None,
     int8_scales=None,
     out_dtype=jnp.float32,
@@ -191,6 +226,7 @@ def stream_conv_block(
     """Fused conv -> bias -> act -> NxN/stride-s-max-pool block (one DHM
     pipeline stage). ``pool=0`` disables pooling, ``pool_stride=None``
     means window == stride (so ``pool=2`` is the classic 2x2/2),
+    ``pool_pad`` pads the pooled map with -inf on every side,
     ``act='none'`` the activation; ``act_bits`` quantizes the output
     feature stream inside the same fused epilogue (the paper's quantized
     pixel flow — no separate HBM pass).
@@ -205,7 +241,7 @@ def stream_conv_block(
         x, w, b,
         padding=padding, stride=stride, act=act, pool=pool,
         pool_stride=pool_stride, act_bits=act_bits, int8_scales=int8_scales,
-        out_dtype=out_dtype, backend=backend,
+        out_dtype=out_dtype, backend=backend, pool_pad=pool_pad,
         block_r=block_r, block_w=block_w, block_c=block_c, block_n=block_n,
     )
 
@@ -221,6 +257,8 @@ def stream_conv_pyramid(
     block_rows: int = 0,
     out_dtype=jnp.float32,
     backend: str = DEFAULT_BACKEND,
+    projections=None,  # None | per layer None or a project join's (w, b)
+    name: str = RESIDUAL_KERNEL,  # the residual kernel's name in a trace
 ) -> jax.Array:
     """Cross-layer fused conv pyramid: a whole fusion group of consecutive
     conv -> bias -> act -> pool layers as ONE kernel invocation, with all
@@ -245,6 +283,12 @@ def stream_conv_pyramid(
     before the kernel (1-byte resident frame), interior layers consume
     and emit int8 stream codes, and each ``Int8Scales.in_bits`` must name
     the previous layer's ``act_bits`` (the code chain contract).
+
+    A layer whose spec carries a residual ``join`` closes a basic block:
+    its shortcut (the block input, or for ``project`` its 1x1 projection
+    by ``projections[i]``, under ``int8_scales[i].proj``) is added before
+    its activation, and the group lowers through the residual kernel
+    (``conv.residual_kernel(name)``), the same body under its own name.
     """
     validate_backend(backend)
     weights = tuple(weights)
@@ -285,15 +329,33 @@ def stream_conv_pyramid(
                     "inter-layer code chain)"
                 )
     pyr = as_pyramid_layers(layers)
+    projections = (
+        (None,) * len(layers) if projections is None else tuple(projections)
+    )
+    for li, (layer, pj) in enumerate(zip(pyr, projections)):
+        if (layer.join == "project") != (pj is not None):
+            raise ValueError(
+                f"pyramid layer {li}: a project join needs its (w, b) "
+                f"projection, and only it takes one (join={layer.join!r})"
+            )
+        if int8_scales is not None and (
+            (layer.join == "project") != (int8_scales[li].proj is not None)
+        ):
+            raise ValueError(
+                f"pyramid layer {li}: a project join needs its own "
+                "Int8Scales (proj), and only it takes one"
+            )
+    projs = tuple(pj for pj in projections if pj is not None)
+    joined = any(layer.join != "none" for layer in pyr)
     if backend == "ref":
         return stream_conv_pyramid_ref(
             x, weights, biases, layers=pyr, act_bits=bits,
-            int8_scales=int8_scales,
+            int8_scales=int8_scales, projections=projections,
         ).astype(out_dtype)
     if backend == "pallas" and not compiled_pallas_available():
         return stream_conv_pyramid_xla(
             x, weights, biases, layers=pyr, act_bits=bits,
-            int8_scales=int8_scales, out_dtype=out_dtype,
+            int8_scales=int8_scales, out_dtype=out_dtype, projections=projs,
         )
     if int8_scales is not None and jnp.issubdtype(x.dtype, jnp.floating):
         # Quantize onto layer 0's stream grid OUTSIDE the pallas_call: the
@@ -301,8 +363,11 @@ def stream_conv_pyramid(
         from repro.core.quant.fixed_point import quantize_fixed
 
         x = quantize_fixed(x, int8_scales[0].in_spec).astype(jnp.int8)
-    return stream_conv_pyramid_pallas(
-        x, weights, biases, layers=pyr, act_bits=bits,
-        int8_scales=int8_scales, block_rows=block_rows, out_dtype=out_dtype,
+    kw = dict(
+        layers=pyr, act_bits=bits, int8_scales=int8_scales,
+        block_rows=block_rows, out_dtype=out_dtype,
         interpret=(backend == "pallas_interpret"),
     )
+    if joined:
+        return residual_kernel(name)(x, weights, biases, projs, **kw)
+    return stream_conv_pyramid_pallas(x, weights, biases, **kw)

@@ -23,6 +23,7 @@ from repro.core.quant.fixed_point import (
     quantize_fixed,
 )
 from repro.kernels.stream_conv.epilogue import ACTS, normalize_pool
+from repro.kernels.stream_conv.halo import BLOCK_SPAN
 
 
 def stream_conv2d_ref(
@@ -49,10 +50,13 @@ def stream_conv_block_ref(
     act: str = "none",
     pool: int = 0,
     pool_stride: int | None = None,
+    pool_pad: int = 0,
     act_bits: int | None = None,
     int8_scales=None,
+    residual=None,
 ) -> jax.Array:
-    """Unfused conv -> bias -> act -> NxN/stride-s max-pool -> fake-quant
+    """Unfused conv -> bias (+ ``residual``) -> act -> NxN/stride-s
+    max-pool (``pool_pad`` -inf rows/cols per side) -> fake-quant
     reference composition.
 
     ``int8_scales`` (an ``epilogue.Int8Scales``) switches the conv to the
@@ -93,6 +97,8 @@ def stream_conv_block_ref(
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
         )
     y = y + b.astype(jnp.float32)
+    if residual is not None:
+        y = y + residual
     if act == "relu":
         y = jnp.maximum(y, 0.0)
     elif act == "tanh":
@@ -104,7 +110,7 @@ def stream_conv_block_ref(
             jax.lax.max,
             window_dimensions=(1, pw, pw, 1),
             window_strides=(1, ps, ps, 1),
-            padding="VALID",
+            padding=((0, 0), (pool_pad, pool_pad), (pool_pad, pool_pad), (0, 0)),
         )
     if act_bits is not None:
         y = fake_quant_ste(y, FixedPointSpec(bits=act_bits, frac_bits=act_bits - 2))
@@ -119,17 +125,34 @@ def stream_conv_pyramid_ref(
     layers,  # PyramidLayer per layer (padding/stride/act/pool/pool_stride)
     act_bits=None,  # int | None | per-layer tuple
     int8_scales=None,  # None | per-layer tuple of Int8Scales
+    projections=None,  # None | per layer None or the join's (w, b)
 ) -> jax.Array:
     """Reference rendering of a fusion group: the plain per-layer
     ``stream_conv_block_ref`` chain. Fusion is a scheduling decision, not
-    a semantic one — the group's math is exactly the layer composition."""
-    n = len(tuple(layers))
+    a semantic one — the group's math is exactly the layer composition.
+    A layer with a ``join`` adds its block's input (the previous layer's
+    input), or that input's 1x1 projection at the block's stride, to its
+    conv output before the activation."""
+    layers = tuple(layers)
+    n = len(layers)
     bits = act_bits if isinstance(act_bits, tuple) else (act_bits,) * n
+    ins = []
     for i, (layer, w, b) in enumerate(zip(layers, weights, biases)):
+        ins.append(x)
+        sc = None if int8_scales is None else int8_scales[i]
+        residual = None
+        if layer.join != "none":
+            residual = ins[i - BLOCK_SPAN + 1]
+            if layer.join == "project":
+                pw_, pb = projections[i]
+                stride = layers[i - 1].stride * layer.stride
+                residual = stream_conv_block_ref(
+                    residual, pw_, pb, stride=stride, act="none",
+                    int8_scales=None if sc is None else sc.proj,
+                )
         x = stream_conv_block_ref(
             x, w, b, padding=layer.padding, stride=layer.stride,
             act=layer.act, pool=layer.pool, pool_stride=layer.pool_stride,
-            act_bits=bits[i],
-            int8_scales=None if int8_scales is None else int8_scales[i],
+            act_bits=bits[i], int8_scales=sc, residual=residual,
         )
     return x

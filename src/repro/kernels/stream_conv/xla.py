@@ -40,7 +40,7 @@ _BLOCK_BYTES_BUDGET = 128 * 1024 * 1024
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "k", "stride", "act", "pool", "pool_stride", "act_bits",
+        "k", "stride", "act", "pool", "pool_stride", "pool_pad", "act_bits",
         "int8_scales", "out_dtype",
     ),
 )
@@ -54,6 +54,7 @@ def stream_conv_fused_xla(
     act: str = "none",
     pool: int = 0,
     pool_stride: int | None = None,
+    pool_pad: int = 0,
     act_bits: int | None = None,
     int8_scales=None,
     out_dtype=jnp.float32,
@@ -89,6 +90,14 @@ def stream_conv_fused_xla(
         raise ValueError(
             f"conv output {h_out}x{w_out} too small for {pw}x{pw} pool"
         )
+    # A padded pool: pool_pad extra conv rows/cols per side (the frame
+    # shifted by pool_pad * stride), masked to -inf before the pool.
+    pp = pool_pad
+    real_hw = (h_out, w_out)
+    if pp:
+        x = jnp.pad(x, ((0, 0), (pp * s, 0), (pp * s, pp * s), (0, 0)))
+        h_out, w_out = h_out + 2 * pp, w_out + 2 * pp
+        h = x.shape[1]
 
     # Row block from the byte budget: largest R (multiple of the pool
     # stride) whose (B, R, W_out, K*K, C) f32 operand fits.
@@ -148,6 +157,7 @@ def stream_conv_fused_xla(
         return apply_epilogue(
             yb, bias, act=act, pool=pool, pool_stride=pool_stride,
             act_bits=act_bits, ste=int8_scales is None,
+            pool_pad=(rb * r - pp, -pp, *real_hw) if pp else None,
         )
 
     if n_rb == 1:
@@ -190,6 +200,7 @@ def stream_conv_pyramid_xla(
     act_bits=None,  # int | None | per-layer tuple
     int8_scales=None,  # None | per-layer tuple of Int8Scales
     out_dtype=jnp.float32,
+    projections: tuple = (),  # (w, b) per ``project`` join, in layer order
 ) -> jax.Array:
     """XLA rendering of the fused pyramid — the compiled fallback where
     Mosaic is unavailable. The whole group is one fused XLA graph (this
@@ -200,11 +211,14 @@ def stream_conv_pyramid_xla(
     feature maps stay whole-frame (CPU memory, not VMEM, is the
     constraint here); if a layer's patch operand would exceed the im2col
     byte budget, the closure degrades to the row-blocked per-layer path
-    so memory stays bounded.
+    so memory stays bounded (a layer that closes a residual join keeps
+    the whole-frame matmul).
     """
-    from repro.kernels.stream_conv.halo import same_pads
+    from repro.kernels.stream_conv.halo import BLOCK_SPAN, same_pads
 
     n_layers = len(layers)
+    proj = iter(projections)
+    ins = []  # each layer's unpadded input (int8 codes on that path)
     bits = act_bits if isinstance(act_bits, tuple) else (act_bits,) * n_layers
     big = any(
         x.shape[0] * g_h * g_w * k * k * c * 4 > _BLOCK_BYTES_BUDGET
@@ -221,11 +235,12 @@ def stream_conv_pyramid_xla(
             from repro.core.quant.fixed_point import quantize_fixed
 
             x = quantize_fixed(x, sc.in_spec).astype(jnp.int8)
+        ins.append((x, None if sc is None else sc.in_scale))
         if layer.padding == "SAME":
             ph = same_pads(x.shape[1], s, k)
             pw_ = same_pads(x.shape[2], s, k)
             x = jnp.pad(x, ((0, 0), ph, pw_, (0, 0)))
-        if big:
+        if big and layer.join == "none":
             # Bounded-memory fallback: same grouping contract (one jitted
             # closure), row-blocked per-layer kernels inside.
             x = stream_conv_fused_xla(
@@ -251,14 +266,38 @@ def stream_conv_pyramid_xla(
                 w_t.reshape(k * k * c, -1).astype(jnp.float32),
                 preferred_element_type=jnp.float32,
             ).reshape(b, conv_r, conv_c, -1)
+        residual = None
+        if layer.join != "none":
+            src, step = ins[i - BLOCK_SPAN + 1]
+            st = layers[i - 1].stride * s
+            src = src[:, : (conv_r - 1) * st + 1 : st, : (conv_c - 1) * st + 1 : st]
+            if layer.join == "identity":
+                residual = src if step is None else src.astype(jnp.float32) * step
+            else:
+                pw_t, pb = next(proj)
+                residual = _conv1x1_xla(src, pw_t, pb, None if sc is None else sc.proj)
         # ste=True: the XLA rendering is the differentiable fused path
         # (the int8 rendering is forward-only).
         x = apply_epilogue(
             y, b_t, act=layer.act, pool=layer.pool,
             pool_stride=layer.pool_stride, act_bits=bits[i],
-            ste=sc is None, pool_first=True,
+            ste=sc is None, pool_first=True, residual=residual,
         )
     return x.astype(out_dtype)
+
+
+def _conv1x1_xla(src, w_t, b_t, sc):
+    """A projection shortcut on its (already strided) source: one matmul
+    (integer into int32 under ``sc``) plus its bias."""
+    b, h, w, c = src.shape
+    op = src.reshape(b * h * w, c)
+    wf = w_t.reshape(c, -1)
+    if sc is not None:
+        y = jnp.dot(op, wf.astype(jnp.int8), preferred_element_type=jnp.int32)
+        y = y.astype(jnp.float32) * sc.deq_scale
+    else:
+        y = jnp.dot(op.astype(jnp.float32), wf.astype(jnp.float32))
+    return y.reshape(b, h, w, -1) + b_t
 
 
 def _pyramid_conv_dims(x_shape, weights, layers):

@@ -28,7 +28,7 @@ from repro.analysis.jaxpr_utils import count_primitive
 from repro.analysis.verify import make_pipeline_probe, verify_plan
 from repro.core.dhm.compiler import PlanCheckError, QuantSpec, compile_dhm
 from repro.core.dhm.pipeline import StageIOSpec
-from repro.models.cnn import ALL_TOPOLOGIES, LENET5, init_cnn
+from repro.models.cnn import ALL_TOPOLOGIES, CHAIN_TOPOLOGIES, LENET5, init_cnn
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -135,7 +135,7 @@ class TestCleanRun:
         """The acceptance matrix: five topologies x fp32/quant, zero
         findings (single-device artifacts; the pipelined closures get
         the same treatment in the CLI and the mesh-gated test below)."""
-        for name, topo in ALL_TOPOLOGIES.items():
+        for name, topo in CHAIN_TOPOLOGIES.items():
             params = init_cnn(jax.random.PRNGKey(0), topo)
             for quant in (QuantSpec(), QuantSpec(weight_bits=6, act_bits=6)):
                 plan = compile_dhm(topo, params, quant=quant)

@@ -17,6 +17,7 @@ from repro.core.dhm.fusion import (
 from repro.kernels.stream_conv import stream_conv_pyramid
 from repro.models.cnn import (
     ALL_TOPOLOGIES,
+    CHAIN_TOPOLOGIES,
     CNNTopology,
     ConvLayerSpec,
     PAPER_TOPOLOGIES,
@@ -96,7 +97,7 @@ class TestPlanner:
             assert [g.layers for g in groups] == [(0,), (1,)]
 
     def test_huge_budget_whole_pyramid(self):
-        for topo in ALL_TOPOLOGIES.values():
+        for topo in CHAIN_TOPOLOGIES.values():
             groups = plan_fusion_groups(
                 topo, range(len(topo.conv_layers)), vmem_budget=2**40
             )
@@ -142,7 +143,7 @@ class TestFusedCorrectness:
     """Composed-halo correctness: fused plans match the hand-composed
     reference on every topology, fp32 and quantized."""
 
-    @pytest.mark.parametrize("name", sorted(ALL_TOPOLOGIES))
+    @pytest.mark.parametrize("name", sorted(CHAIN_TOPOLOGIES))
     def test_fused_plan_matches_reference_compiled(self, name):
         topo = ALL_TOPOLOGIES[name]
         params, x = _mk_inputs(topo)
@@ -153,7 +154,7 @@ class TestFusedCorrectness:
             np.asarray(plan(x)), np.asarray(ref), rtol=1e-4, atol=1e-4
         )
 
-    @pytest.mark.parametrize("name", sorted(ALL_TOPOLOGIES))
+    @pytest.mark.parametrize("name", sorted(CHAIN_TOPOLOGIES))
     def test_fused_quant_plan_matches_reference(self, name):
         bits = {"lenet5": 3}.get(name, 6)
         topo = ALL_TOPOLOGIES[name]

@@ -34,6 +34,7 @@ from repro.kernels.stream_conv.epilogue import Int8Scales, stream_quant_spec
 from repro.kernels.stream_conv.ref import stream_conv_block_ref
 from repro.models.cnn import (
     ALL_TOPOLOGIES,
+    CHAIN_TOPOLOGIES,
     CNNTopology,
     ConvLayerSpec,
     init_cnn,
@@ -299,7 +300,7 @@ class TestInt8FusionWidening:
         fp32 plan cannot fuse the full conv stack, the int8 plan can —
         1-byte slabs buy a strictly larger group under the SAME budget."""
         widened = []
-        for name, topo in ALL_TOPOLOGIES.items():
+        for name, topo in CHAIN_TOPOLOGIES.items():
             idxs = tuple(range(len(topo.conv_layers)))
             probe = widening_budget(topo, idxs)
             if probe is None:
@@ -311,7 +312,7 @@ class TestInt8FusionWidening:
     def test_compiled_plans_realize_the_widening(self):
         """Compile fp32 and int8 plans at the probe budget and compare
         the actual fusion groups the compiler emitted."""
-        for name, topo in ALL_TOPOLOGIES.items():
+        for name, topo in CHAIN_TOPOLOGIES.items():
             idxs = tuple(range(len(topo.conv_layers)))
             probe = widening_budget(topo, idxs)
             if probe is None or probe["int8_max_group"] <= probe["fp32_max_group"]:
@@ -338,7 +339,7 @@ class TestInt8FusionWidening:
     def test_fp32_costing_unchanged(self):
         """elem_bytes=4 defaults reproduce the historical costs — fp32
         plans keep byte-identical working sets."""
-        for topo in ALL_TOPOLOGIES.values():
+        for topo in CHAIN_TOPOLOGIES.values():
             idxs = tuple(range(len(topo.conv_layers)))
             a = group_working_set(topo, idxs, block_rows=8)
             b = group_working_set(topo, idxs, block_rows=8, elem_bytes=4)

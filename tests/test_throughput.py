@@ -30,7 +30,7 @@ from repro.core.dhm.throughput import (
     streaming_throughput,
     sweep_sample,
 )
-from repro.models.cnn import ALL_TOPOLOGIES, init_cnn
+from repro.models.cnn import ALL_TOPOLOGIES, CHAIN_TOPOLOGIES, init_cnn
 
 
 class TestStreamingLaw:
@@ -105,7 +105,7 @@ class TestPlanEdges:
         with pytest.raises(ValueError, match="edge mode"):
             plan_edges(_specs((4,), (2,)), mode="wat")
 
-    @pytest.mark.parametrize("name", sorted(ALL_TOPOLOGIES))
+    @pytest.mark.parametrize("name", sorted(CHAIN_TOPOLOGIES))
     def test_every_topology_takes_exact_path(self, name):
         """Structural: every shipped topology's interior edges fit the
         class budget, so the compiled plan streams exact-shape edges —

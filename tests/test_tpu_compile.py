@@ -113,3 +113,23 @@ def test_packed_pow2_head_compiles(name, one_chip, mosaic):
     assert plan.quant.packed_fc_head
     n_fc = len(plan.topo.fc_dims) + 1
     assert _compile_kernels(plan, one_chip) == 1 + n_fc
+
+
+def test_resnet18_plan_compiles(one_chip, mosaic):
+    """ResNet-18 at 224 px in int8: the stem's row-blocked kernel (its
+    7x7/2 conv folded space-to-depth, its padded pool) and one residual
+    kernel per group of whole basic blocks, each named after its layers,
+    fit Mosaic's VMEM and tiling rules."""
+    import re
+
+    plan = _plan("resnet18", quant=QuantSpec(weight_bits=8, act_bits=8,
+                                             int8_compute=True))
+    groups = plan.fusion_groups
+    x = jax.ShapeDtypeStruct((BATCH, 224, 224, 3), jnp.float32, sharding=one_chip)
+    text = plan.jitted_forward(donate=True).lower(x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == len(groups)
+    names = set(re.findall(r"%(stream_conv_residual_pallas_l\d+_\d+)\.\d+ = ", text))
+    assert names == {
+        f"stream_conv_residual_pallas_l{g.layers[0]}_{g.layers[-1]}"
+        for g in groups[1:]
+    }
