@@ -188,11 +188,26 @@ def make_params(topology: dict, key_words):
     return jax.jit(init)(key)
 
 
+def _fold_host(w, b, gamma, beta, mean, var):
+    w, b, gamma, beta, mean, var = (
+        np.asarray(a, np.float64) for a in (w, b, gamma, beta, mean, var)
+    )
+    g = gamma / np.sqrt(var + EPS)
+    return (w * g).astype(np.float32), ((b - mean) * g + beta).astype(np.float32)
+
+
 def _fold(p: dict) -> dict:
     """Batch norm folded into the conv: ``g = gamma / sqrt(var + eps)``,
-    kernel ``w * g``, bias ``(b - mean) * g + beta``."""
-    g = p["gamma"] / jnp.sqrt(p["var"] + EPS)
-    return {"w": p["w"] * g, "b": (p["b"] - p["mean"]) * g + p["beta"]}
+    kernel ``w * g``, bias ``(b - mean) * g + beta``, computed on the host
+    in float64 and rounded once to float32, as offline folding does. In
+    float32 on the device the fold's last bit depends on how XLA fuses
+    it, and that bit moves some of the 8-bit codes."""
+    out = (jax.ShapeDtypeStruct(p["w"].shape, jnp.float32),
+           jax.ShapeDtypeStruct(p["b"].shape, jnp.float32))
+    w, b = jax.pure_callback(
+        _fold_host, out, p["w"], p["b"], p["gamma"], p["beta"], p["mean"], p["var"]
+    )
+    return {"w": w.astype(p["w"].dtype), "b": b.astype(p["b"].dtype)}
 
 
 def forward(params, x, topology: dict, *, fake_quant_bits, dot_operands):

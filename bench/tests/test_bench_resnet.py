@@ -104,6 +104,34 @@ def test_matches_the_program_oracle(bits):
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * scale)
 
 
+def test_batch_norm_folds_alike_in_the_program_and_the_reference():
+    """The folded kernel's 8-bit codes must not hang on how a compiler
+    fuses the fold: the reference's fold, jitted whole as the harness
+    runs it, and the program's, run at compile time, agree bit for bit
+    on a stage-4-sized kernel. A float32 fold fails this: jitted and op
+    by op it differs in the last bit of many weights, which moved a few
+    codes of ResNet-18 and every logit past 2^-10 of the largest."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models.cnn import fold_bn
+
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    n = 512
+    p = {
+        "w": 0.03 * jax.random.normal(ks[0], (3, 3, 512, n)),
+        "b": jnp.zeros((n,)),
+        "gamma": jax.random.uniform(ks[1], (n,), minval=0.2, maxval=0.5),
+        "beta": 0.1 * jax.random.normal(ks[2], (n,)),
+        "mean": 0.1 * jax.random.normal(ks[3], (n,)),
+        "var": jax.random.uniform(ks[4], (n,), minval=0.5, maxval=2.0),
+    }
+    ref = jax.jit(resnet._fold)(p)
+    prog = fold_bn(p)
+    for k in ("w", "b"):
+        np.testing.assert_array_equal(np.asarray(ref[k]), np.asarray(prog[k]))
+
+
 def test_program_plan_is_correct_and_a_perturbed_one_is_not():
     """The program's int8 plan, compiled by the family's ``system``,
     reads correct against the family's reference under the

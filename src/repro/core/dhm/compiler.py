@@ -452,10 +452,7 @@ def emit_conv_stage(
                     ),
                     block_rows=block_rows,
                     backend=resolved,
-                    name=(
-                        f"stream_conv_residual_pallas_l{first_layer + g[0]}"
-                        f"_{first_layer + g[-1]}"
-                    ),
+                    first_layer=first_layer + g[0],
                 )
         return x
 
@@ -611,6 +608,10 @@ def _emit_head(
                     (first_spec if i == 0 else qact_spec) if int_head else None
                 )
                 h = _pow2_linear_ste(h, p["w"], backend, x_spec) + p["b"]
+            elif global_pool:
+                # The pooled average is off every grid, so the MXU's
+                # default one-pass bf16 would round it: full float32.
+                h = jnp.dot(h, p["w"], precision=jax.lax.Precision.HIGHEST) + p["b"]
             else:
                 h = h @ p["w"] + p["b"]
             if i < len(baked) - 1:

@@ -616,12 +616,12 @@ RESIDUAL_KERNEL = "stream_conv_residual_pallas"
 
 
 @functools.lru_cache(maxsize=None)
-def residual_kernel(name: str = RESIDUAL_KERNEL):
+def residual_kernel(first: int, last: int):
     """The pyramid kernel of a fusion group that carries residual joins,
-    jitted under ``name``, which the device trace shows as the kernel's
-    event name (``%<name>.<n> = ...``); the compiler names each group's
-    kernel ``stream_conv_residual_pallas_l<first>_<last>`` after the conv
-    layers it computes, so a trace reader can price every call.
+    computing the plan's conv layers ``first``..``last``. It is jitted
+    under the name ``stream_conv_residual_pallas_l<first>_<last>``, which
+    the device trace shows as the kernel's event name
+    (``%<name>.<n> = ...``), so a trace reader can price every call.
 
     Same kernel body as :func:`stream_conv_pyramid_pallas`: each basic
     block's input slab stays in VMEM from its first layer to the join,
@@ -643,7 +643,7 @@ def residual_kernel(name: str = RESIDUAL_KERNEL):
             compiler_params=WIDE_VMEM,
         )
 
-    kernel.__name__ = kernel.__qualname__ = name
+    kernel.__name__ = kernel.__qualname__ = f"{RESIDUAL_KERNEL}_l{first}_{last}"
     return jax.jit(
         kernel,
         static_argnames=(

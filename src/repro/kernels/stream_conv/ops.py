@@ -31,7 +31,6 @@ from repro.kernels.backends import (
     validate_backend,
 )
 from repro.kernels.stream_conv.conv import (
-    RESIDUAL_KERNEL,
     residual_kernel,
     stream_conv_fused_pallas,
     stream_conv_pyramid_pallas,
@@ -258,7 +257,7 @@ def stream_conv_pyramid(
     out_dtype=jnp.float32,
     backend: str = DEFAULT_BACKEND,
     projections=None,  # None | per layer None or a project join's (w, b)
-    name: str = RESIDUAL_KERNEL,  # the residual kernel's name in a trace
+    first_layer: int | None = None,  # plan index of layers[0]; joins need it
 ) -> jax.Array:
     """Cross-layer fused conv pyramid: a whole fusion group of consecutive
     conv -> bias -> act -> pool layers as ONE kernel invocation, with all
@@ -288,7 +287,9 @@ def stream_conv_pyramid(
     its shortcut (the block input, or for ``project`` its 1x1 projection
     by ``projections[i]``, under ``int8_scales[i].proj``) is added before
     its activation, and the group lowers through the residual kernel
-    (``conv.residual_kernel(name)``), the same body under its own name.
+    (``conv.residual_kernel``), the same body under a name of its own that
+    gives the plan's indices of the group's layers: ``first_layer`` is
+    that of ``layers[0]``, and a group with a join cannot go without it.
     """
     validate_backend(backend)
     weights = tuple(weights)
@@ -347,6 +348,11 @@ def stream_conv_pyramid(
             )
     projs = tuple(pj for pj in projections if pj is not None)
     joined = any(layer.join != "none" for layer in pyr)
+    if joined and first_layer is None:
+        raise ValueError(
+            "a pyramid group with a residual join needs its first_layer: "
+            "its kernel is named after the plan layers it computes"
+        )
     if backend == "ref":
         return stream_conv_pyramid_ref(
             x, weights, biases, layers=pyr, act_bits=bits,
@@ -369,5 +375,6 @@ def stream_conv_pyramid(
         interpret=(backend == "pallas_interpret"),
     )
     if joined:
-        return residual_kernel(name)(x, weights, biases, projs, **kw)
+        kernel = residual_kernel(first_layer, first_layer + len(pyr) - 1)
+        return kernel(x, weights, biases, projs, **kw)
     return stream_conv_pyramid_pallas(x, weights, biases, **kw)

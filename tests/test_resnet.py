@@ -152,6 +152,26 @@ def test_plan_matches_reference(backend, quant):
     assert [g.layers for g in plan.fusion_groups] == [(0,), (1, 2, 3, 4)]
 
 
+@pytest.mark.parametrize("quant", [QuantSpec(), INT8], ids=["fp32", "int8"])
+def test_pooled_head_asks_for_full_float32(quant):
+    """The globally pooled features lie on no grid, so the MXU's default
+    one-pass bf16 would round them and move every logit by about 2^-9 of
+    its size: the pooled head's dot asks for HIGHEST precision. (The CPU
+    computes float32 products either way, so only the request can be
+    checked here.)"""
+    plan = compile_dhm(TINY, _params(), quant=quant, backend="ref")
+    feats = jax.ShapeDtypeStruct((2, 2, 2, TINY.conv_layers[-1].n_out), jnp.float32)
+    dots = [
+        e for e in jax.make_jaxpr(plan.head_fn)(feats).eqns
+        if e.primitive.name == "dot_general"
+    ]
+    assert dots
+    for e in dots:
+        assert e.params["precision"] == (
+            jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST
+        )
+
+
 def test_bn_folding_matches_unfolded_reference():
     """Folding BN into each kernel and bias (projections too) is exact
     math: the folded conv agrees with conv-then-BN within 1e-5."""
@@ -217,7 +237,7 @@ def test_join_kernel_matches_lax_float(join, block_rows):
     for backend in ("pallas_interpret", "pallas"):
         got = stream_conv_pyramid(
             x, ws, bs, layers=specs, block_rows=block_rows, backend=backend,
-            projections=[None, proj],
+            projections=[None, proj], first_layer=0,
         )
         np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
 
@@ -273,8 +293,29 @@ def test_join_kernel_matches_lax_int8(join, block_rows):
         got = stream_conv_pyramid(
             x, ws, bs, layers=specs, act_bits=bits, int8_scales=int8_scales,
             block_rows=block_rows, backend=backend, projections=[None, proj],
+            first_layer=0,
         )
         np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("backend", PALLAS)
+def test_join_kernel_is_named_after_its_layers(backend):
+    """A group with a join runs under the name that gives its plan layers,
+    which the residual metrics read from the device trace; without the
+    index of its first layer it is refused on every backend, so no
+    residual kernel can run under a name those metrics cannot read."""
+    specs = _block_specs("identity")
+    x = jnp.zeros((1, 7, 9, 8))
+    ws = [jnp.zeros((3, 3, 8, 8))] * 2
+    bs = [jnp.zeros((8,))] * 2
+    with pytest.raises(ValueError, match="first_layer"):
+        stream_conv_pyramid(x, ws, bs, layers=specs, backend=backend)
+    text = jax.jit(
+        lambda a: stream_conv_pyramid(
+            a, ws, bs, layers=specs, backend="pallas_interpret", first_layer=3,
+        )
+    ).lower(x).as_text()
+    assert "stream_conv_residual_pallas_l3_4" in text
 
 
 @pytest.mark.parametrize("hw, k, stride, block_r", [
@@ -459,6 +500,27 @@ def test_resnet18_plan(resnet18_plan):
         (2, 224, 224, 3), jnp.float32
     ))
     assert logits.shape == (2, 1000)
+
+
+def test_resnet18_kernels_match_the_reference_at_224px(resnet18_plan):
+    """The stem's kernel and the four residual kernels at ResNet-18's own
+    shapes (224 px, whole-frame blocks, 64 to 512 channels, both kinds of
+    join), run as the exact kernel program through the interpreter,
+    against the hand-composed oracle: exact in 8 bits."""
+    params = init_cnn(jax.random.PRNGKey(0), RESNET18)
+    interp = compile_dhm(RESNET18, params, quant=INT8, backend="pallas_interpret")
+    assert [g.layers for g in interp.fusion_groups] == [
+        g.layers for g in resnet18_plan.fusion_groups
+    ]
+    x = _grid_frames((1, 224, 224, 3), seed=9)
+    want = cnn_apply_reference(params, RESNET18, x, weight_bits=8, act_bits=8)
+    np.testing.assert_array_equal(
+        np.asarray(interp.features(x)),
+        np.asarray(resnet18_plan.features(x)),
+    )
+    np.testing.assert_allclose(
+        interp(x), want, rtol=0, atol=1e-6 * float(jnp.abs(want).max())
+    )
 
 
 @pytest.mark.parametrize("backend", ["pallas", "pallas_interpret"])
