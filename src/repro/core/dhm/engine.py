@@ -302,8 +302,11 @@ class EngineStats:
     micro-batches two deep: it launches group k+1 (stage and closure
     call, without waiting) before it finishes group k (waits for it,
     fetches its logits and checks them on the host copy).
-    ``pack_s`` (numpy concat and zero-pad), ``stage_s`` (queuing the
-    asynchronous host-to-device copy into a fresh buffer),
+    ``pack_s`` (building each micro-batch's host array: a copy of the
+    requests' frames it spans into one fresh array, zero-padded past
+    their end, or nothing where it is a view of one request's frames),
+    ``stage_s`` (queuing the asynchronous host-to-device copy into a
+    fresh buffer),
     ``device_wait_s`` (the blocking wait in a group's finish, which holds
     whatever of the copy, its layout transpose and the program is still
     to run), ``fetch_s`` (the device-to-host read of the logits) and
@@ -315,7 +318,10 @@ class EngineStats:
     padding included, beside ``n_frames`` answered. ``n_overlapped``
     counts the micro-batches (of ``n_batches``) launched while an
     earlier one of the same flush was unfinished: ``n_overlapped /
-    n_batches`` is (G-1)/G over flushes of G groups."""
+    n_batches`` is (G-1)/G over flushes of G groups. ``n_direct``
+    counts the micro-batches (of ``n_batches``) staged straight from one
+    request's frames, with no host copy before the stage: those a single
+    request covers whole, C-contiguous and of the flush's dtype."""
 
     n_requests: int
     n_frames: int
@@ -342,6 +348,7 @@ class EngineStats:
     complete_s: float = 0.0
     n_slots: int = 0
     n_overlapped: int = 0
+    n_direct: int = 0
 
     @property
     def frames_per_busy_s(self) -> float:
@@ -360,7 +367,8 @@ class EngineStats:
     def summary(self) -> str:
         s = (
             f"{self.n_requests} requests / {self.n_frames} frames in "
-            f"{self.n_batches} micro-batches: "
+            f"{self.n_batches} micro-batches ({self.n_direct} staged "
+            "direct): "
             f"{self.frames_per_busy_s:.0f} frames/busy-s, latency mean "
             f"{self.mean_latency_s * 1e3:.2f} ms "
             f"max {self.max_latency_s * 1e3:.2f} ms"
@@ -388,17 +396,21 @@ class EngineStats:
 @dataclasses.dataclass
 class _Flight:
     """One launched micro-batch: its logits on the device until the
-    finish fetches them (then None), the injected stall the finish
+    finish fetches them (then None), its host frames (the finish's
+    poisoned-input check reads them), the injected stall the finish
     sleeps, its ``group`` span row, when the closure was called (the
-    ``forward`` span's start), its stage seconds, and whether an earlier
-    group of its flush was unfinished at its launch."""
+    ``forward`` span's start), its stage seconds, whether an earlier
+    group of its flush was unfinished at its launch, and whether its
+    frames are a view of one request's frames."""
 
     out: Optional[jax.Array]
+    frames: np.ndarray
     stall_s: float
     row: int
     t_launch: float
     stage_s: float
     overlapped: bool
+    direct: bool
 
 
 # ---------------------------------------------------------------------------
@@ -559,6 +571,7 @@ class Engine:
         self._batches = 0
         self._slots = 0
         self._overlapped = 0
+        self._direct = 0
         self._busy_s = 0.0
         self._phase_s = [0.0] * len(_PHASES)
         self._n_flushes = 0
@@ -785,9 +798,10 @@ class Engine:
         e.g. circuit open — before it ever touches the queue). Malformed
         shapes raise ``ValueError``; gate failures return the request
         already completed with :class:`InvalidRequest`."""
-        # Queued frames live on the HOST: the flush packs variable request
-        # counts with numpy (eager device concats would compile per
-        # distinct shape) and only the fixed-shape packed group is staged
+        # Queued frames live on the HOST: the flush builds each group's
+        # array with numpy (eager device concats would compile per
+        # distinct shape), or takes it as it is where one request's
+        # frames fill the group, and only that fixed-shape array is staged
         # onto the device.
         x = np.asarray(x)
         if x.shape == self._frame_shape:
@@ -913,12 +927,13 @@ class Engine:
     # -- dispatch: faults, watchdog, retry, demotion --------------------------
 
     def _stage(self, batch: jax.Array) -> jax.Array:
-        """Stage a packed micro-batch into a fresh buffer the closure can
-        consume. The copy is what makes donation safe (the caller's arrays
-        stay valid and a failed dispatch can restage for its retry). The
-        copy is queued asynchronously, so within a flush batch k+1 is
-        staged and launched while batch k is still being computed on —
-        the double-buffered serving path (:meth:`_run_groups`)."""
+        """Stage a micro-batch's host array into a fresh buffer the closure
+        can consume. The copy is what makes donation safe (the caller's
+        arrays, which a micro-batch staged direct is a view of, stay valid,
+        and a failed dispatch can restage for its retry). The copy is
+        queued asynchronously, so within a flush batch k+1 is staged and
+        launched while batch k is still being computed on — the
+        double-buffered serving path (:meth:`_run_groups`)."""
         return jnp.array(batch, copy=True)
 
     def _corrupted_forward(self, frames: jax.Array, stage: int) -> jax.Array:
@@ -932,8 +947,60 @@ class Engine:
                 x = jnp.full_like(x, jnp.nan)
         return self.plan.head_fn(x)
 
+    def _split(self, parts: list) -> list:
+        """The frames of ``parts`` (the requests' host arrays, in order)
+        cut into micro-batches of ``group``: for each, the views of the
+        requests' frames it holds. The last may hold fewer frames."""
+        g = self.group
+        groups, cur, room = [], [], g
+        for p in parts:
+            s = 0
+            while s < len(p):
+                take = min(room, len(p) - s)
+                cur.append(p[s : s + take])
+                s += take
+                room -= take
+                if not room:
+                    groups.append(cur)
+                    cur, room = [], g
+        if cur:
+            groups.append(cur)
+        return groups
+
+    def _pack(self, parts: list, dtype, row: int, phases: list) -> tuple:
+        """The host array of the micro-batch whose frames are ``parts``
+        (views of requests' frames, in order), and whether it is staged
+        direct. One part that fills the group, C-contiguous and of
+        ``dtype``, is used as it is; otherwise the parts are copied into
+        one fresh array of ``dtype``, zero-padded past their end. Adds its
+        seconds to ``phases`` and records a ``pack`` span under the
+        group's span ``row``."""
+        t_p = time.perf_counter()
+        g = self.group
+        first = parts[0]
+        direct = (
+            len(parts) == 1 and len(first) == g and first.dtype == dtype
+            and first.flags.c_contiguous
+        )
+        if direct:
+            x = first
+        else:
+            x = np.empty((g,) + self._frame_shape, dtype)
+            off = 0
+            for p in parts:
+                x[off : off + len(p)] = p
+                off += len(p)
+            x[off:] = 0
+        t_q = time.perf_counter()
+        phases[_PACK] += t_q - t_p
+        log = self._spans
+        if log is not None:
+            log.add(spans.PACK, 0, row, t_p, t_q)
+        return x, direct
+
     def _launch(
-        self, frames: np.ndarray, row: int = -1, overlapped: bool = False
+        self, frames: np.ndarray, row: int = -1, overlapped: bool = False,
+        direct: bool = False,
     ) -> _Flight:
         """Launch one exactly-``group``-sized batch on the active rung
         without waiting for it: draw its fault effects (an injected
@@ -941,7 +1008,8 @@ class Engine:
         (or the corrupted forward under an injected NaN). Runs on the
         calling thread; :meth:`_finish` waits for the result. ``row`` is
         the group's span row; ``overlapped`` says an earlier group of the
-        flush is still unfinished."""
+        flush is still unfinished; ``direct`` that ``frames`` is a view of
+        one request's frames."""
         stall_s, corrupt = 0.0, None
         if self._faults is not None:
             eff = self._faults.dispatch_effects(
@@ -961,11 +1029,11 @@ class Engine:
         log = self._spans
         if log is not None:
             log.add(spans.STAGE, 0, row, t_a, t_b)
-        return _Flight(out, stall_s, row, t_b, t_b - t_a, overlapped)
+        return _Flight(
+            out, frames, stall_s, row, t_b, t_b - t_a, overlapped, direct
+        )
 
-    def _finish(
-        self, fl: _Flight, frames: np.ndarray, phases: list
-    ) -> np.ndarray:
+    def _finish(self, fl: _Flight, phases: list) -> np.ndarray:
         """Wait for a launched group under the watchdog
         (``dispatch_timeout_s``; an injected stall is slept first), fetch
         its logits to the host and check them there. Returns the host
@@ -1001,6 +1069,7 @@ class Engine:
             self._batches += 1
             self._slots += self.group
             self._overlapped += fl.overlapped
+            self._direct += fl.direct
         phases[_STAGE] += fl.stage_s
         phases[_DEVICE_WAIT] += t_d - t_c
         phases[_FETCH] += t_e - t_d
@@ -1011,7 +1080,7 @@ class Engine:
             log.add(spans.FETCH, 0, fl.row, t_d, t_e)
             log.add(spans.CHECK, 0, fl.row, t_e, t_f)
         if not ok:
-            if not bool(np.isfinite(np.asarray(frames)).all()):
+            if not bool(np.isfinite(fl.frames).all()):
                 raise _PoisonedBatch(
                     "packed batch carries non-finite input frames"
                 )
@@ -1023,29 +1092,34 @@ class Engine:
 
     def _run_group(
         self,
-        frames: np.ndarray,
+        parts: list,
         phases: Optional[list] = None,
         parent: int = -1,
         gid: int = 0,
-        real: Optional[int] = None,
         failed: Optional[Exception] = None,
+        dtype=None,
     ) -> np.ndarray:
-        """Run one exactly-``group``-sized batch through the active rung,
-        one launch and finish at a time: fault effects applied, watchdog
-        timeout, bounded retry-with-backoff on transient failures,
-        demotion on persistent ones. Returns the logits on the host.
-        Raises :class:`LadderExhausted` when no rung can complete the
-        batch, or :class:`_PoisonedBatch` when the inputs themselves are
-        non-finite (the flush isolates per request). ``failed`` is the
-        error of an attempt already made on this batch by the pipelined
-        flush; it counts as the first attempt.
+        """Run one micro-batch, whose frames are ``parts`` (at most
+        ``group`` of them, in order), through the active rung, one launch
+        and finish at a time: fault effects applied, watchdog timeout,
+        bounded retry-with-backoff on transient failures, demotion on
+        persistent ones. Its host array is built once (:meth:`_pack`, as
+        ``dtype``, by default the parts' own result type) and kept until
+        it finishes. Returns the logits on the host. Raises
+        :class:`LadderExhausted` when no rung can complete the batch, or
+        :class:`_PoisonedBatch` when the inputs themselves are non-finite
+        (the flush isolates per request). ``failed`` is the error of an
+        attempt already made on this batch by the pipelined flush; it
+        counts as the first attempt.
 
-        Adds the stage, device-wait, fetch and check seconds to
+        Adds the pack, stage, device-wait, fetch and check seconds to
         ``phases`` (the flush's per-phase list); with spans armed,
-        records a ``group`` span (``gid``, holding ``real`` frames) under
-        the span row ``parent``."""
+        records a ``group`` span (``gid``, holding the parts' frames)
+        under the span row ``parent``."""
         if phases is None:
             phases = [0.0] * len(_PHASES)
+        if dtype is None:
+            dtype = np.result_type(*(p.dtype for p in parts))
         log = self._spans
         grow = (
             -1 if log is None
@@ -1054,13 +1128,14 @@ class Engine:
         backoff = self.retry_backoff_s
         retries_left = self.max_retries
         try:
+            frames, direct = self._pack(parts, dtype, grow, phases)
             while True:
                 try:
                     if failed is not None:
                         err, failed = failed, None
                         raise err
                     return self._finish(
-                        self._launch(frames, grow), frames, phases
+                        self._launch(frames, grow, direct=direct), phases
                     )
                 except _PoisonedBatch:
                     raise
@@ -1092,24 +1167,23 @@ class Engine:
                         backoff = self.retry_backoff_s
         finally:
             if log is not None:
-                log.end(
-                    grow, time.perf_counter(),
-                    self.group if real is None else real,
-                )
+                log.end(grow, time.perf_counter(), sum(map(len, parts)))
 
-    def _run_groups(
-        self, frames: np.ndarray, n: int, phases: list, parent: int
-    ) -> list:
-        """Run a flush's packed ``frames`` (``n`` of them real) group by
-        group, two deep: launch group k+1, then finish group k, so the
-        device computes k+1 while the host waits for, fetches and checks
-        k. At most two groups are in flight. After a failure in the
-        launch or the finish of group k, a launched k+1 is dropped, and
-        group k and the rest of the flush run through :meth:`_run_group`,
-        the failed attempt counting as group k's first. Returns the host
-        logits of each group, in order."""
-        g = self.group
-        count = frames.shape[0] // g
+    def _run_groups(self, parts: list, phases: list, parent: int) -> list:
+        """Run a flush's frames, the requests' host arrays ``parts`` in
+        order, group by group, two deep: launch group k+1, then finish
+        group k, so the device computes k+1 while the host waits for,
+        fetches and checks k. Each group's host array is built in its
+        launch (:meth:`_pack`), as the result type of every part's dtype,
+        so a packed group's copy runs while the device computes the group
+        before it. At most two groups are in flight. After a failure in
+        the launch or the finish of group k, a launched k+1 is dropped,
+        and group k and the rest of the flush run through
+        :meth:`_run_group`, the failed attempt counting as group k's
+        first. Returns the host logits of each group, in order."""
+        dtype = np.result_type(*(p.dtype for p in parts))
+        groups = self._split(parts)
+        count = len(groups)
         log = self._spans
 
         def end(fl, arg):
@@ -1122,7 +1196,8 @@ class Engine:
                 else log.begin(spans.GROUP, k, parent, time.perf_counter())
             )
             try:
-                return self._launch(frames[k * g : (k + 1) * g], row, k > 0)
+                frames, direct = self._pack(groups[k], dtype, row, phases)
+                return self._launch(frames, row, k > 0, direct)
             except Exception:
                 if log is not None:
                     log.end(row, time.perf_counter(), 0)
@@ -1142,9 +1217,7 @@ class Engine:
                 except Exception as e:  # noqa: BLE001 — after finishing k
                     failed = e
             try:
-                outs.append(
-                    self._finish(ahead, frames[k * g : (k + 1) * g], phases)
-                )
+                outs.append(self._finish(ahead, phases))
             except Exception as e:  # noqa: BLE001 — rerun through _run_group
                 end(ahead, 0)
                 if nxt is not None:
@@ -1154,12 +1227,13 @@ class Engine:
                     raise
                 failed = e
                 break
-            end(ahead, min(g, n - k * g))
+            end(ahead, sum(map(len, groups[k])))
             ahead, k = nxt, k + 1
+        ahead = nxt = None  # let go of their arrays: the reruns rebuild theirs
         for j in range(k, count):
             outs.append(self._run_group(
-                frames[j * g : (j + 1) * g], phases, parent, j,
-                min(g, n - j * g), failed if j == k else None,
+                groups[j], phases, parent, j, failed if j == k else None,
+                dtype,
             ))
         return outs
 
@@ -1237,26 +1311,14 @@ class Engine:
         ph = [0.0] * len(_PHASES)
         done = None
         try:
-            # Pack on the HOST: the request count (and so the concat/pad
-            # shapes) varies per flush, and eager jnp ops compile once per
-            # distinct shape — numpy packing keeps the device path at the
-            # one fixed group shape the jitted closure was compiled for.
-            t_p = time.perf_counter()
-            frames = np.concatenate(
-                [np.asarray(r._frames) for r in live], axis=0
+            # The frames stay on the HOST, in the requests' own arrays:
+            # each group's array is built there in its launch, at the one
+            # fixed group shape the jitted closure was compiled for (eager
+            # jnp concats would compile once per distinct request count).
+            n = sum(r.n_frames for r in live)
+            outs = self._run_groups(
+                [np.asarray(r._frames) for r in live], ph, frow
             )
-            n = frames.shape[0]
-            pad = -n % self.group
-            if pad:
-                frames = np.concatenate(
-                    [frames,
-                     np.zeros((pad,) + self._frame_shape, frames.dtype)]
-                )
-            t_q = time.perf_counter()
-            ph[_PACK] = t_q - t_p
-            if log is not None:
-                log.add(spans.PACK, 0, frow, t_p, t_q)
-            outs = self._run_groups(frames, n, ph, frow)
             logits = (
                 outs[0][:n] if len(outs) == 1
                 else np.concatenate(outs, axis=0)[:n]
@@ -1314,19 +1376,11 @@ class Engine:
                     ),
                 )
                 continue
-            pad = -req.n_frames % self.group
-            if pad:
-                x = np.concatenate(
-                    [x, np.zeros((pad,) + self._frame_shape, x.dtype)]
-                )
             try:
-                outs = []
-                for start in range(0, x.shape[0], self.group):
-                    outs.append(np.asarray(self._run_group(
-                        x[start : start + self.group], phases, parent,
-                        start // self.group,
-                        min(self.group, req.n_frames - start),
-                    )))
+                outs = [
+                    self._run_group(parts, phases, parent, j)
+                    for j, parts in enumerate(self._split([x]))
+                ]
                 logits = np.concatenate(outs, axis=0)[: req.n_frames]
             except (LadderExhausted, _PoisonedBatch) as e:
                 self._fail(
@@ -1496,6 +1550,7 @@ class Engine:
                 n_batches=self._batches,
                 n_slots=self._slots,
                 n_overlapped=self._overlapped,
+                n_direct=self._direct,
                 busy_s=self._busy_s,
                 **dict(zip(_PHASES, self._phase_s)),
                 mean_latency_s=(
@@ -1532,6 +1587,7 @@ class Engine:
             self._batches = 0
             self._slots = 0
             self._overlapped = 0
+            self._direct = 0
             self._busy_s = 0.0
             self._phase_s = [0.0] * len(_PHASES)
             self._lat_n = 0

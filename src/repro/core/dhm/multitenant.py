@@ -412,7 +412,7 @@ class Router:
             pass
         probe = np.zeros((eng.group,) + eng._frame_shape, np.float32)
         try:
-            out = eng._run_group(probe)
+            out = eng._run_group([probe])
             return bool(np.isfinite(np.asarray(out)).all())
         except Exception as e:  # noqa: BLE001 — a failed probe re-opens
             _LOG.info("tenant %r probe dispatch failed: %s", ts.name, e)

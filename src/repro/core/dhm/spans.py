@@ -19,7 +19,9 @@ Span kinds, with the parent each is recorded under:
 - ``flush`` (id: flush number; arg: live requests): one flush.
 - ``queued`` (id: request index; parent: the flush that took it): from
   ``submitted_at`` to the take.
-- ``pack`` (parent: the flush): numpy concat and zero-pad.
+- ``pack`` (parent: the group): building the group's host array, a
+  copy of the requests' frames it spans, zero-padded past their end;
+  no copy where the group is a view of one request's frames.
 - ``group`` (id: group number within its flush; parent: the flush; arg:
   real frames, 0 for a launch dropped or handed to the serial retry): one
   micro-batch from its launch to the end of its finish, retries

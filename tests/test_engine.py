@@ -22,6 +22,7 @@ from repro.core.dhm.engine import (
 )
 from repro.core.dhm.faults import (
     DelayedFlush,
+    DispatchError,
     FaultPlan,
     NaNActivation,
     StalledDispatch,
@@ -384,6 +385,19 @@ class TestEngineSpans:
         assert [getattr(st, f) for f in self.INSIDE_BUSY] == [0.0] * 5
         assert st.complete_s == 0.0 and st.n_slots == 0
 
+    def test_direct_count_lies_within_batches_and_resets(self):
+        topo, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4)
+        eng.infer(_frames(topo, 10))  # groups of 4, 4 and 2 + 2 padding
+        eng.submit(_frames(topo, 1, seed=2))
+        eng.submit(_frames(topo, 3, seed=3))
+        eng.flush()  # one group gathered from two requests
+        st = eng.stats()
+        assert st.n_batches == 4 and st.n_direct == 2
+        assert "(2 staged direct)" in st.summary()
+        eng.reset_stats()
+        assert eng.stats().n_direct == 0
+
     def test_slots_count_the_padding_of_a_flush(self):
         topo, plan = _plan("lenet5")
         eng = Engine(plan, microbatch=4)
@@ -406,7 +420,9 @@ class TestEngineSpans:
         cols = log.columns()
         kind, parent = cols["kind"], cols["parent"]
         flush, group = spans.KINDS.index("flush"), spans.KINDS.index("group")
-        children = [self._by_kind(log, k) for k in ("stage", "forward", "check")]
+        children = [
+            self._by_kind(log, k) for k in ("pack", "stage", "forward", "check")
+        ]
         assert all(len(rows) == 4 for rows in children)  # 3 + 1 groups
         for rows in children:
             assert (kind[parent[rows]] == group).all()
@@ -503,12 +519,93 @@ class TestPipelinedFlush:
         x = np.asarray(_frames(topo, 14))
         reqs = [eng.submit(p) for p in self._split(x, (5, 1, 7, 1))]
         eng.flush()  # 14 frames -> four groups in one flush
-        assert eng.stats().n_batches == 4
+        st = eng.stats()
+        # Groups 0 and 2 lie inside one request: staged straight from it.
+        assert st.n_batches == 4 and st.n_direct == 2
         got = np.concatenate([r.result() for r in reqs])
         alone = np.concatenate(
             [np.asarray(eng.infer(x[s : s + 4])) for s in range(0, 14, 4)]
         )
         np.testing.assert_array_equal(got, alone)
+
+    def test_requests_that_fill_their_groups_are_all_staged_direct(self):
+        topo, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4)
+        x = np.asarray(_frames(topo, 16))
+        reqs = [eng.submit(p) for p in self._split(x, (4, 4, 4, 4))]
+        eng.flush()
+        st = eng.stats()
+        assert st.n_batches == st.n_direct == 4
+        got = np.concatenate([r.result() for r in reqs])
+        alone = np.concatenate(
+            [np.asarray(eng.infer(x[s : s + 4])) for s in range(0, 16, 4)]
+        )
+        np.testing.assert_array_equal(got, alone)
+
+    def test_mixed_dtypes_stage_every_group_as_the_result_type(self):
+        topo, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4)
+        staged = []
+        stage = eng._stage
+
+        def spy(batch):
+            staged.append(batch.dtype)
+            return stage(batch)
+
+        eng._stage = spy
+        x = np.asarray(_frames(topo, 12)).astype(np.float16).astype(np.float32)
+        parts = self._split(x, (4, 4, 4))
+        reqs = [
+            eng.submit(parts[0]),
+            eng.submit(parts[1].astype(np.float16)),  # exact: from float16
+            eng.submit(parts[2]),
+        ]
+        eng.flush()
+        assert staged == [np.float32] * 3
+        # The float16 request's group is cast in a copy, not staged direct.
+        assert eng.stats().n_direct == 2
+        assert plan_jitted_forward(plan, donate=True)._cache_size() == 1
+        got = np.concatenate([r.result() for r in reqs])
+        alone = np.concatenate([np.asarray(eng.infer(p)) for p in parts])
+        np.testing.assert_array_equal(got, alone)
+
+    def test_callers_array_is_unchanged_by_donated_direct_groups(self):
+        topo, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4)
+        assert eng.donate
+        x = np.array(_frames(topo, 8))  # a writable array of the caller's
+        before = x.copy()
+        first = np.asarray(eng.infer(x))
+        second = np.asarray(eng.infer(x))
+        assert eng.stats().n_direct == 4
+        np.testing.assert_array_equal(x, before)
+        np.testing.assert_array_equal(first, second)
+
+    def test_dispatch_error_in_a_direct_group_retries_bit_equal(self):
+        topo, plan = _plan("lenet5")
+        eng = Engine(
+            plan, microbatch=4, retry_backoff_s=1e-4,
+            fault_plan=FaultPlan([DispatchError(at=1, times=1)]),
+        )
+        x = np.asarray(_frames(topo, 12))
+        got = np.asarray(eng.infer(x))  # the launch of group 1 raises once
+        st = eng.stats()
+        assert st.n_retries == 1 and st.n_demotions == 0
+        assert st.n_batches == st.n_direct == 3
+        clean = Engine(plan, microbatch=4)
+        np.testing.assert_array_equal(got, np.asarray(clean.infer(x)))
+
+    def test_strided_request_is_packed_not_staged_direct(self):
+        topo, plan = _plan("lenet5")
+        eng = Engine(plan, microbatch=4)
+        x = np.asarray(_frames(topo, 8))[::2]  # 4 frames, not contiguous
+        assert not x.flags.c_contiguous
+        got = np.asarray(eng.infer(x))
+        st = eng.stats()
+        assert st.n_batches == 1 and st.n_direct == 0
+        want = np.asarray(eng.infer(np.ascontiguousarray(x)))
+        assert eng.stats().n_direct == 1
+        np.testing.assert_array_equal(got, want)
 
     @pytest.mark.parametrize("n_groups", [1, 3])
     def test_overlapped_counts_groups_launched_behind_another(self, n_groups):
